@@ -50,7 +50,7 @@ class IMPALA:
             vs, pg_adv = vtrace(jax.lax.stop_gradient(log_rhos), discounts,
                                 traj["reward"],
                                 jax.lax.stop_gradient(v_t), boot,
-                                self.clip_rho, self.clip_c)
+                                self.clip_rho, self.clip_c, use_kernel=True)
         else:  # naive on-policy targets computed from off-policy data
             def disc_ret(acc, xs):
                 r, d = xs

@@ -8,10 +8,8 @@ so the transformer policy trunk (networks.TrunkPolicy) trains through
 one call site on every backend. Both paths share the oracle; parity is
 pinned in tests/test_kernels.py.
 """
-import jax.numpy as jnp
-
 from repro.kernels.common import interpret_mode
-from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.flash_attention.ref import attention_ref_grouped
 
 
 def attention(qg, k, v, *, causal=True, window=0, use_kernel=False):
@@ -23,8 +21,4 @@ def attention(qg, k, v, *, causal=True, window=0, use_kernel=False):
     if use_kernel and not interpret_mode():
         from repro.kernels.flash_attention.ops import flash_attention
         return flash_attention(qg, k, v, causal=causal, window=window)
-    B, S, KVH, G, D = qg.shape
-    q = jnp.moveaxis(qg.reshape(B, S, KVH * G, D), 1, 2)  # (B, H, S, D)
-    o = attention_ref(q, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2),
-                      causal=causal, window=window)
-    return jnp.moveaxis(o, 1, 2).reshape(B, S, KVH, G, D)
+    return attention_ref_grouped(qg, k, v, causal=causal, window=window)

@@ -409,16 +409,23 @@ class DistPlan:
         return s
 
     # ---- mesh construction --------------------------------------------
-    def validate_devices(self, n_available: int) -> None:
+    def validate_devices(self, devices) -> None:
         """Clear error instead of silently slicing/wrapping devices."""
+        n_available = len(devices)
         if self.n_devices > n_available:
             shape = "x".join(f"{a.name}={a.size}" for a in self.axes)
-            raise RuntimeError(
-                f"DistPlan mesh ({shape}) needs {self.n_devices} devices "
-                f"but only {n_available} {'is' if n_available == 1 else 'are'} "
-                f"visible; set XLA_FLAGS=--xla_force_host_platform_"
-                f"device_count={self.n_devices} before importing jax "
-                f"(the rl_train CLI does this automatically)")
+            msg = (f"DistPlan mesh ({shape}) needs {self.n_devices} devices "
+                   f"but only {n_available} "
+                   f"{'is' if n_available == 1 else 'are'} visible")
+            platform = devices[0].platform
+            if platform == "cpu":
+                msg += (f"; set XLA_FLAGS=--xla_force_host_platform_"
+                        f"device_count={self.n_devices} before importing "
+                        f"jax (the rl_train CLI does this automatically)")
+            else:
+                msg += (f"; found {n_available} {platform} device(s) "
+                        f"({devices[0].device_kind})")
+            raise RuntimeError(msg)
 
     def build_mesh(self, devices=None):
         """Mesh over the first `n_devices` visible devices, row-major:
@@ -427,7 +434,7 @@ class DistPlan:
         nesting never permutes which envs/RNG streams a device owns."""
         from jax.sharding import Mesh
         devices = jax.devices() if devices is None else devices
-        self.validate_devices(len(devices))
+        self.validate_devices(devices)
         devs = np.asarray(devices[:self.n_devices]).reshape(
             self.mesh_shape)
         return Mesh(devs, self.axis_names)

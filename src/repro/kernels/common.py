@@ -1,26 +1,7 @@
-"""Shared kernel helpers: interpret-mode selection + compiler params."""
+"""Shared kernel helper: interpret-mode selection."""
 import jax
-
-try:  # TPU compiler params — name moved across JAX versions
-    from jax.experimental.pallas import tpu as pltpu
-    if hasattr(pltpu, "TPUCompilerParams"):
-        CompilerParams = pltpu.TPUCompilerParams
-    else:
-        CompilerParams = pltpu.CompilerParams
-except Exception:  # pragma: no cover
-    pltpu = None
-    CompilerParams = None
 
 
 def interpret_mode() -> bool:
     """Pallas-TPU kernels execute in interpret mode off-TPU (CPU CI)."""
     return jax.default_backend() != "tpu"
-
-
-def compiler_params(dimension_semantics):
-    if CompilerParams is None or interpret_mode():
-        return None
-    try:
-        return CompilerParams(dimension_semantics=dimension_semantics)
-    except TypeError:  # pragma: no cover
-        return None

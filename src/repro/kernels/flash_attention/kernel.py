@@ -13,8 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pltpu, interpret_mode, compiler_params
+from repro.kernels.common import interpret_mode
 
 NEG_INF = -1e30
 
@@ -88,11 +89,9 @@ def flash_attention_hsd(q, k, v, *, causal=True, window=0, bq=128, bk=128,
     kernel = functools.partial(_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
                                window=window, scale=scale,
                                valid_len=valid_len)
-    scratch = None
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq, D), jnp.float32)]
+    scratch = [pltpu.VMEM((bq,), jnp.float32),
+               pltpu.VMEM((bq,), jnp.float32),
+               pltpu.VMEM((bq, D), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
@@ -107,8 +106,9 @@ def flash_attention_hsd(q, k, v, *, causal=True, window=0, bq=128, bk=128,
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=scratch,
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret_mode(),
     )(q, k, v)
     return out

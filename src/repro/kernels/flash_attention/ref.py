@@ -24,3 +24,14 @@ def attention_ref(q, k, v, *, causal=True, window=0):
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, vv).astype(q.dtype)
+
+
+def attention_ref_grouped(qg, k, v, *, causal=True, window=0):
+    """`attention_ref` in the model's grouped-query layout.
+    qg: (B, S, KVH, G, D); k, v: (B, S, KVH, D). Returns (B, S, KVH, G,
+    D)."""
+    B, S, KVH, G, D = qg.shape
+    q = jnp.moveaxis(qg.reshape(B, S, KVH * G, D), 1, 2)  # (B, H, S, D)
+    o = attention_ref(q, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2),
+                      causal=causal, window=window)
+    return jnp.moveaxis(o, 1, 2).reshape(B, S, KVH, G, D)

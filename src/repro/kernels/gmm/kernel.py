@@ -9,8 +9,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pltpu, interpret_mode, compiler_params
+from repro.kernels.common import interpret_mode
 
 
 def _kernel(xref, wref, oref, accref, *, nd):
@@ -36,9 +37,6 @@ def gmm_ecd(x, w, *, bc=128, bf=128, bd=512):
     f = w.shape[-1]
     nc, nf, nd = C // bc, f // bf, d // bd
     kernel = functools.partial(_kernel, nd=nd)
-    scratch = None
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bc, bf), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(E, nc, nf, nd),
@@ -49,8 +47,9 @@ def gmm_ecd(x, w, *, bc=128, bf=128, bd=512):
         out_specs=pl.BlockSpec((1, bc, bf),
                                lambda e, ic, if_, id_: (e, ic, if_)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
-        scratch_shapes=scratch,
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret_mode(),
     )(x, w)

@@ -1,4 +1,9 @@
-"""Jit'd wrapper: pad batch, call the Pallas V-trace kernel."""
+"""Jit'd wrapper: pad batch, call the Pallas V-trace kernel.
+
+V-trace targets are constants of the loss (both outputs are
+stop-gradient, as in the ref), so the inputs are cut from the
+gradient too: no tangent reaches the kernel, which has no JVP rule.
+"""
 import jax
 import jax.numpy as jnp
 
@@ -7,6 +12,8 @@ from repro.kernels.vtrace.kernel import vtrace_tb
 
 def vtrace(log_rhos, discounts, rewards, values, bootstrap,
            clip_rho=1.0, clip_c=1.0, bb=128):
+    log_rhos, discounts, rewards, values, bootstrap = jax.lax.stop_gradient(
+        (log_rhos, discounts, rewards, values, bootstrap))
     T, B = log_rhos.shape
     bb = min(bb, B)
     pad = (-B) % bb
@@ -21,5 +28,4 @@ def vtrace(log_rhos, discounts, rewards, values, bootstrap,
                         values.astype(jnp.float32),
                         bootstrap.astype(jnp.float32),
                         clip_rho=clip_rho, clip_c=clip_c, bb=bb)
-    return (jax.lax.stop_gradient(vs[:, :B]),
-            jax.lax.stop_gradient(adv[:, :B]))
+    return vs[:, :B], adv[:, :B]

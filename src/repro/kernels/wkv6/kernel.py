@@ -14,8 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pltpu, interpret_mode, compiler_params
+from repro.kernels.common import interpret_mode
 
 
 def _kernel(rref, kref, vref, wref, uref, yref, Sref, *, L, N):
@@ -63,9 +64,6 @@ def wkv6_btHN(r, k, v, logw, u, *, chunk=64):
     nc = T // chunk
     kernel = functools.partial(_kernel, L=chunk, N=N)
     spec = pl.BlockSpec((1, chunk, 1, N), lambda b, h, ic: (b, ic, h, 0))
-    scratch = None
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((N, N), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(B, H, nc),
@@ -73,8 +71,8 @@ def wkv6_btHN(r, k, v, logw, u, *, chunk=64):
                   pl.BlockSpec((1, N), lambda b, h, ic: (h, 0))],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, N), jnp.float32),
-        scratch_shapes=scratch,
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(r, k, v, logw, u)

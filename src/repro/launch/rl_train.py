@@ -183,6 +183,9 @@ def main(argv=None):
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{n_devices}").strip()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import repro.envs as envs
     from repro.core import agent as agent_api
     from repro.core.distribution import DistPlan

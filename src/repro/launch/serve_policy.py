@@ -175,9 +175,11 @@ def main(argv=None):
     import jax
     import repro.envs as envs
     from benchmarks.common import write_bench_json
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core.serving import ParamStore, ServeEngine
     from repro.core.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     if args.env not in envs.available():
         ap.error(f"--env {args.env} not registered; available: "
                  f"{envs.available()}")

@@ -112,6 +112,18 @@ def test_plan_device_validation_names_count_and_shape():
     assert "xla_force_host_platform_device_count" in msg
 
 
+def test_plan_device_validation_off_cpu_names_platform():
+    """On an accelerator the fake-CPU-device advice does not apply: the
+    error names the platform and the chips it found instead."""
+    import types
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    with pytest.raises(RuntimeError) as e:
+        DistPlan.flat(4).validate_devices([chip])
+    msg = str(e.value)
+    assert "4 devices" in msg and "1 tpu device(s) (TPU v5 lite)" in msg
+    assert "xla_force_host_platform_device_count" not in msg
+
+
 # --------------------------------------------------- shard-role grammar
 def test_plan_parse_shard_role_round_trip():
     s = "workers=4:allreduce:bsp,shard=2:allreduce:bsp:shard"

@@ -69,6 +69,35 @@ def test_flash_wrapper_layout(rng):
     np.testing.assert_allclose(o1, o2, atol=2e-5)
 
 
+@pytest.mark.parametrize("S,causal,window", [
+    (4, True, 0),                      # trunk feature mode (padded S)
+    (128, True, 32),                   # sliding window
+    (96, False, 0),                    # non-causal, padded S
+])
+def test_flash_custom_vjp_matches_ref_grad(S, causal, window, rng):
+    """flash_attention's custom VJP (Pallas forward, backward through
+    the oracle) gives the value and the gradients of `attention_ref` in
+    the grouped layout, for every input."""
+    from repro.kernels.flash_attention.ref import attention_ref_grouped
+    B, KVH, G, D = 2, 2, 2, 32
+    ks = jax.random.split(rng, 4)
+    qg = jax.random.normal(ks[0], (B, S, KVH, G, D))
+    k = jax.random.normal(ks[1], (B, S, KVH, D))
+    v = jax.random.normal(ks[2], (B, S, KVH, D))
+    ct = jax.random.normal(ks[3], (B, S, KVH, G, D))
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a, causal=causal, window=window)
+                                  * ct)
+
+    l1, g1 = jax.value_and_grad(loss(flash_attention), (0, 1, 2))(qg, k, v)
+    l2, g2 = jax.value_and_grad(loss(attention_ref_grouped),
+                                (0, 1, 2))(qg, k, v)
+    np.testing.assert_allclose(l1, l2, rtol=1e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("B,S,KVH,G,D,causal,window", [
     (2, 128, 2, 2, 32, True, 0),
     (1, 256, 1, 4, 64, True, 64),      # sliding window, MQA kv=1
@@ -200,6 +229,35 @@ def test_advantages_kernel_sweep(T, B, rng):
     np.testing.assert_allclose(n1, n2, atol=1e-5, rtol=1e-5)
 
 
+def test_scan_kernels_differentiate_like_refs(rng):
+    """Gradients through the kernel wrappers (interpret mode) equal the
+    refs': n-step returns carry the bootstrap value's gradient (A3C's
+    value loss), V-trace targets carry none (both stop-gradient)."""
+    T, B = 16, 9
+    rew, val, dones, boot = _adv_inputs(T, B, rng)
+    ct = jax.random.normal(jax.random.fold_in(rng, 7), (T, B))
+
+    def nstep_loss(fn):
+        return lambda r, b: jnp.sum(fn(r, dones, b, 0.99) * ct)
+
+    g1 = jax.grad(nstep_loss(adv_ops.nstep_return), (0, 1))(rew, boot)
+    g2 = jax.grad(nstep_loss(nstep_return_ref), (0, 1))(rew, boot)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    assert float(jnp.abs(g1[1]).sum()) > 0.0
+
+    disc = 0.99 * (1.0 - dones.astype(jnp.float32))
+
+    def vtrace_loss(fn):
+        return lambda v, b: sum(jnp.sum(o * ct) for o in
+                                fn(0.1 * rew, disc, rew, v, b))
+
+    g1 = jax.grad(vtrace_loss(vtrace_k), (0, 1))(val, boot)
+    g2 = jax.grad(vtrace_loss(vtrace_ref), (0, 1))(val, boot)
+    for a, b in zip(g1, g2):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_advantages_generic_recurrence(rng):
     T, B = 50, 40
     ks = jax.random.split(rng, 3)
@@ -254,6 +312,7 @@ def test_advantages_ref_pins_legacy_inline_scans(rng):
     (256, 17, 16),                     # nearly-empty, n == size-1 range
     (131, 100, 1),                     # odd capacity, single draw
     (64, 10, 32),                      # degenerate n > size fallback
+    (16384, 12000, 16),                # several row blocks of the draw
 ])
 def test_replay_sample_kernel_matches_ref(C, size, n, rng):
     ks = jax.random.split(rng, 2)
@@ -314,6 +373,29 @@ def test_shard_topk_merge_matches_flat_sample(size, rng):
     w = prioritized_weights_ref(prio, size, idx)
     assert np.array_equal(np.asarray(fi), np.asarray(idx))
     assert np.array_equal(np.asarray(fw), np.asarray(w))
+
+
+@pytest.mark.parametrize("C,nvalid,k", [
+    (64, 40, 16),                      # one padded row block
+    (300, 300, 32),                    # full shard, odd capacity
+    (16384, 9000, 8),                  # several row blocks of the draw
+    (64, 5, 16),                       # surplus: k > nvalid
+    (64, 0, 4),                        # empty shard: only -inf
+])
+def test_shard_topk_kernel_matches_ref(C, nvalid, k, rng):
+    """The per-shard Pallas candidate draw (interpret mode) picks the
+    ref's indices bitwise, ties included, with the ref's scores to f32
+    rounding (the kernel evaluates log on (R, 128) tiles)."""
+    from repro.kernels.replay_sample.ops import shard_topk
+    ks = jax.random.split(rng, 2)
+    prio = jnp.abs(jax.random.normal(ks[0], (C,))) + 0.01
+    prio = prio.at[1::7].set(prio[0])          # priority ties
+    gumbel = jax.random.gumbel(ks[1], (C,))
+    gumbel = gumbel.at[1::7].set(gumbel[0])    # -> exact score ties
+    s1, i1 = shard_gumbel_topk_ref(prio, nvalid, gumbel, k)
+    s2, i2 = shard_topk(prio, jnp.int32(nvalid), gumbel, k)
+    assert np.array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_allclose(s1, s2, rtol=1e-6)
 
 
 def test_shard_topk_dispatcher_kernel_flag_off_tpu(rng):

@@ -654,6 +654,25 @@ def test_cli_rejects_zero3_on_wrong_collective_naming_segment():
     assert "'s'" in r.stderr and "allreduce" in r.stderr
 
 
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """The CLIs' compile cache: a set JAX_COMPILATION_CACHE_DIR is the
+    cache and the helper sets nothing; unset, the cache goes to the
+    fixed <repo>/.jax_cache."""
+    from repro.launch.compile_cache import enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    repo_cache = os.path.realpath(
+        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert str(enable_compile_cache()) == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert str(enable_compile_cache()) == repo_cache
+        assert jax.config.jax_compilation_cache_dir == repo_cache
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 # ------------------------------------------- learning sanity (migrated)
 def test_impala_policy_lag_vtrace_beats_naive():
     """Survey §6.1: under policy lag, V-trace correction must not be
