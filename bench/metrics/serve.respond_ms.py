@@ -1,0 +1,8 @@
+"""Mean host time per `ServeEngine.step` in its `serve.respond` span:
+building the response records. From the program's own spans
+(bench/program_spans.py); none recorded: no reading."""
+from bench import program_spans
+
+
+def read(ctx):
+    return program_spans.per_serve_step_ms("serve.respond")

@@ -178,8 +178,9 @@ class PolicyGradientAgent(Agent):
             state.params, traj, boot_obs)
         if grad_tx is not None:
             grads = grad_tx(grads)
-        params, opt_state = self.opt.apply(state.params, state.opt_state,
-                                           grads)
+        with jax.named_scope("optimizer"):
+            params, opt_state = self.opt.apply(state.params,
+                                               state.opt_state, grads)
         if param_tx is not None:
             params = param_tx(params)
         return TrainState(params, opt_state, state.extra,

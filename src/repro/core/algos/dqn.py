@@ -253,8 +253,9 @@ class DQNAgent(Agent):
             loss_online, has_aux=True)(state.params["online"])
         if grad_tx is not None:
             grads = grad_tx(grads)
-        online, opt_state = self.opt.apply(state.params["online"],
-                                           state.opt_state, grads)
+        with jax.named_scope("optimizer"):
+            online, opt_state = self.opt.apply(state.params["online"],
+                                               state.opt_state, grads)
         if param_tx is not None:
             online = param_tx(online)
         warm = state.steps >= self.warmup

@@ -117,8 +117,9 @@ class PPOAgent(PolicyGradientAgent):
                                                                  mbatch)
                 if grad_tx is not None:
                     grads = grad_tx(grads)
-                params, opt_state = self.opt.apply(params, opt_state,
-                                                   grads)
+                with jax.named_scope("optimizer"):
+                    params, opt_state = self.opt.apply(params, opt_state,
+                                                       grads)
                 return (params, opt_state), loss
 
             (params, opt_state), losses = jax.lax.scan(

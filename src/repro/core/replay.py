@@ -65,19 +65,22 @@ class UniformReplay:
     def add_batch(self, state, batch):
         """batch: pytree with leading dim n (n > capacity keeps only the
         last `capacity` items — see module docstring)."""
-        n = jax.tree_util.tree_leaves(batch)[0].shape[0]
-        idx, batch, _, ptr = _ring_fit(state, batch, self.capacity)
-        store = jax.tree_util.tree_map(
-            lambda s, b: s.at[idx].set(b), state["store"], batch)
-        return {"store": store, "ptr": ptr,
-                "size": jnp.minimum(state["size"] + n, self.capacity)}
+        with jax.named_scope("replay.insert"):
+            n = jax.tree_util.tree_leaves(batch)[0].shape[0]
+            idx, batch, _, ptr = _ring_fit(state, batch, self.capacity)
+            store = jax.tree_util.tree_map(
+                lambda s, b: s.at[idx].set(b), state["store"], batch)
+            return {"store": store, "ptr": ptr,
+                    "size": jnp.minimum(state["size"] + n, self.capacity)}
 
     def sample(self, state, key, n):
         """Uniform over filled slots. Empty buffer -> slot-0 zeros (see
         module docstring)."""
-        idx = jax.random.randint(key, (n,), 0, jnp.maximum(state["size"],
-                                                           1))
-        return jax.tree_util.tree_map(lambda s: s[idx], state["store"]), idx
+        with jax.named_scope("replay.sample"):
+            idx = jax.random.randint(key, (n,), 0,
+                                     jnp.maximum(state["size"], 1))
+            return (jax.tree_util.tree_map(lambda s: s[idx],
+                                           state["store"]), idx)
 
 
 @dataclasses.dataclass
@@ -97,45 +100,51 @@ class PrioritizedReplay:
                 "size": jnp.zeros((), jnp.int32)}
 
     def add_batch(self, state, batch, priorities=None):
-        n = jax.tree_util.tree_leaves(batch)[0].shape[0]
-        idx, batch, priorities, ptr = _ring_fit(state, batch,
-                                                self.capacity, priorities)
-        store = jax.tree_util.tree_map(
-            lambda s, b: s.at[idx].set(b), state["store"], batch)
-        if priorities is None:  # new samples get max priority (Ape-X)
-            priorities = jnp.full((idx.shape[0],), jnp.maximum(
-                state["prio"].max(), 1.0))
-        prio = state["prio"].at[idx].set(priorities)
-        return {"store": store, "prio": prio, "ptr": ptr,
-                "size": jnp.minimum(state["size"] + n, self.capacity)}
+        with jax.named_scope("replay.insert"):
+            n = jax.tree_util.tree_leaves(batch)[0].shape[0]
+            idx, batch, priorities, ptr = _ring_fit(
+                state, batch, self.capacity, priorities)
+            store = jax.tree_util.tree_map(
+                lambda s, b: s.at[idx].set(b), state["store"], batch)
+            if priorities is None:  # new samples get max priority (Ape-X)
+                priorities = jnp.full((idx.shape[0],), jnp.maximum(
+                    state["prio"].max(), 1.0))
+            prio = state["prio"].at[idx].set(priorities)
+            return {"store": store, "prio": prio, "ptr": ptr,
+                    "size": jnp.minimum(state["size"] + n, self.capacity)}
 
     def sample(self, state, key, n):
         """-> (batch, idx, is_weights). Proportional to p_i^α; WITH
         replacement on the legacy path, WITHOUT (Gumbel-top-k) on the
         fused path. Empty buffer -> finite-weight slot-0 draws."""
-        if self.fused:
-            gumbel = jax.random.gumbel(key, (self.capacity,))
-            idx, w = fused_prioritized_sample(
-                state["prio"], state["size"], gumbel, n,
-                self.alpha, self.beta, self.eps, use_kernel=True)
-        else:
-            # the arange guard keeps slot 0 "valid" when empty so the
-            # normalization below stays NaN-free (bitwise unchanged
-            # whenever size >= 1)
-            valid = jnp.arange(self.capacity) < jnp.maximum(state["size"],
-                                                            1)
-            logits = self.alpha * jnp.log(state["prio"] + self.eps)
-            logits = jnp.where(valid, logits, -jnp.inf)
-            idx = jax.random.categorical(key, logits, shape=(n,))
-            # π_idx gathered from the chosen logits + scalar partition
-            # function — no capacity-sized softmax materialization
-            unnorm = jnp.exp(logits - jnp.max(logits))
-            N = jnp.maximum(state["size"], 1)
-            w = (N * (unnorm[idx] / unnorm.sum()) + 1e-12) ** (-self.beta)
-            w = w / jnp.maximum(w.max(), 1e-12)
-        batch = jax.tree_util.tree_map(lambda s: s[idx], state["store"])
-        return batch, idx, w
+        with jax.named_scope("replay.sample"):
+            if self.fused:
+                gumbel = jax.random.gumbel(key, (self.capacity,))
+                idx, w = fused_prioritized_sample(
+                    state["prio"], state["size"], gumbel, n,
+                    self.alpha, self.beta, self.eps, use_kernel=True)
+            else:
+                # the arange guard keeps slot 0 "valid" when empty so
+                # the normalization below stays NaN-free (bitwise
+                # unchanged whenever size >= 1)
+                valid = (jnp.arange(self.capacity)
+                         < jnp.maximum(state["size"], 1))
+                logits = self.alpha * jnp.log(state["prio"] + self.eps)
+                logits = jnp.where(valid, logits, -jnp.inf)
+                idx = jax.random.categorical(key, logits, shape=(n,))
+                # π_idx gathered from the chosen logits + scalar
+                # partition function — no capacity-sized softmax
+                # materialization
+                unnorm = jnp.exp(logits - jnp.max(logits))
+                N = jnp.maximum(state["size"], 1)
+                w = ((N * (unnorm[idx] / unnorm.sum()) + 1e-12)
+                     ** (-self.beta))
+                w = w / jnp.maximum(w.max(), 1e-12)
+            batch = jax.tree_util.tree_map(lambda s: s[idx],
+                                           state["store"])
+            return batch, idx, w
 
     def update_priorities(self, state, idx, td_errors):
-        prio = state["prio"].at[idx].set(jnp.abs(td_errors) + self.eps)
-        return dict(state, prio=prio)
+        with jax.named_scope("replay.update_priorities"):
+            prio = state["prio"].at[idx].set(jnp.abs(td_errors) + self.eps)
+            return dict(state, prio=prio)

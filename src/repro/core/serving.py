@@ -6,17 +6,15 @@ learning).
 Training (repro.core.trainer) owns throughput; this module owns
 *latency under load*. It mirrors the Trainer seam on the traffic side:
 
-  * **`serve_step`** — a jitted, donated micro-batch program per bucket
+  * **`serve_step`** — a jitted micro-batch program per bucket
     size. One program evaluates `agent.actor_policy`-compatible
     behavior params on a `(bucket, *obs_shape)` request batch: each
     request's action/log-prob/value comes from ONE
     `policy.sample_value` evaluation keyed by `fold_in(base_key,
     request_id)`, so a response depends only on (engine seed, request
     id, params) — never on which other requests happened to share the
-    micro-batch. The small device-resident stats carry (requests
-    served / batches dispatched) is donated to its same-shaped output,
-    Trainer-superstep style; params are NOT donated — they are shared
-    by every in-flight batch and across `ParamStore` versions.
+    micro-batch. Nothing is donated: params are shared by every
+    in-flight batch and across `ParamStore` versions.
 
   * **`RequestBatcher`** — host-side FIFO admission queue. Requests
     are never dropped and never reordered: `take` returns the oldest
@@ -49,6 +47,12 @@ Training (repro.core.trainer) owns throughput; this module owns
     so in-flight batches finish on the version they started with and
     every response is tagged with the version that produced it.
 
+While a profiler session runs, `ServeEngine.step` records a
+`serve.step` span with four children that cover it (`serve.admit`,
+`serve.dispatch`, `serve.read_back`, `serve.respond`) and counts
+`serve.rows`, `serve.bucket_rows` and `serve.queue_wait_s` at admission
+(repro.core.spans); otherwise it records nothing.
+
 Offered-load latency/throughput is measured by
 `repro.launch.serve_policy` -> repo-root BENCH_serve.json (p50/p99 at
 varying offered load and bucket configurations), schema-guarded by
@@ -63,6 +67,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import spans
 
 
 # --------------------------------------------------------- param store
@@ -235,7 +241,7 @@ class ServeEngine:
 
     `policy` is any rollout-engine policy (`sample_value`), `obs_space`
     the env's observation Space (padding template), `store` the
-    ParamStore the engine reads at every dispatch. One jitted, donated
+    ParamStore the engine reads at every dispatch. One jitted
     `serve_step` program exists per bucket size; `compile_count` counts
     traces (== XLA compiles) and stays flat under live traffic, batch
     size variation and param hot-swap once `warmup()` has run."""
@@ -251,9 +257,8 @@ class ServeEngine:
         self._base_key = jax.random.PRNGKey(seed)
         self._fns: Dict[int, Any] = {}
         self._compiles = 0
-        # device-resident stats carry, donated through every dispatch
-        self._sstate = {"served": jnp.zeros((), jnp.int32),
-                        "batches": jnp.zeros((), jnp.int32)}
+        self._served = 0        # rows dispatched, padding excluded
+        self._batches = 0       # dispatches
 
     @classmethod
     def for_agent(cls, agent, env, **kw):
@@ -276,15 +281,15 @@ class ServeEngine:
 
     @property
     def stats(self) -> Dict[str, int]:
-        """Host view of the donated device stats carry."""
-        return {k: int(v) for k, v in self._sstate.items()}
+        """Requests served and batches dispatched, warmup included."""
+        return {"served": self._served, "batches": self._batches}
 
     def _bucket_fn(self, bucket: int):
         if bucket in self._fns:
             return self._fns[bucket]
         policy = self.policy
 
-        def serve_step(params, sstate, base_key, obs, ids, n_valid):
+        def serve_step(params, base_key, obs, ids):
             # trace-time side effect: each execution of this Python
             # body is exactly one XLA compilation of this bucket
             self._compiles += 1
@@ -293,12 +298,10 @@ class ServeEngine:
                 return policy.sample_value(
                     params, o, jax.random.fold_in(base_key, i))
 
-            action, logp, value = jax.vmap(one)(obs, ids)
-            sstate = {"served": sstate["served"] + n_valid,
-                      "batches": sstate["batches"] + 1}
-            return sstate, action, logp, value
+            with jax.named_scope("serve_step"):
+                return jax.vmap(one)(obs, ids)
 
-        fn = jax.jit(serve_step, donate_argnums=(1,))
+        fn = jax.jit(serve_step)
         self._fns[bucket] = fn
         return fn
 
@@ -327,11 +330,17 @@ class ServeEngine:
             raise ValueError(f"{len(obs_rows)} rows do not fit "
                              f"bucket {bucket}")
         obs, pids = self._pad_rows(obs_rows, ids, bucket)
-        self._sstate, action, logp, value = self._bucket_fn(bucket)(
-            params, self._sstate, self._base_key, obs, pids,
-            jnp.int32(len(obs_rows)))
         n = len(obs_rows)
+        action, logp, value = self._dispatch(params, obs, pids, n)
         return action[:n], logp[:n], value[:n]
+
+    def _dispatch(self, params, obs, pids, n: int):
+        """Every serve_step call goes through here: one padded bucket
+        of which the first `n` rows are live."""
+        self._served += n
+        self._batches += 1
+        return self._bucket_fn(len(pids))(params, self._base_key, obs,
+                                          pids)
 
     def warmup(self):
         """Compile every bucket program once (against the current
@@ -356,25 +365,38 @@ class ServeEngine:
         (`{"id", "action", "logp", "value", "version", "latency_s"}`,
         also recorded in `self.results`). Returns [] when nothing is
         admissible."""
-        reqs = self.batcher.take(self.max_bucket, now=now)
-        if not reqs:
-            return []
-        version, params = self.store.get()
-        bucket = bucket_for(len(reqs), self.buckets)
-        action, logp, value = self.eval_bucket(
-            [r["obs"] for r in reqs], [r["id"] for r in reqs], bucket,
-            params=params)
-        action, logp, value = jax.device_get((action, logp, value))
-        done = time.perf_counter()
-        out = []
-        for j, r in enumerate(reqs):
-            resp = {"id": r["id"], "action": action[j],
-                    "logp": float(logp[j]), "value": float(value[j]),
-                    "version": version,
-                    "latency_s": done - r["arrival"]}
-            self.results[r["id"]] = resp
-            out.append(resp)
-        return out
+        with spans.span("serve.step"):
+            with spans.span("serve.admit"):
+                reqs = self.batcher.take(self.max_bucket, now=now)
+                if not reqs:
+                    return []
+                n = len(reqs)
+                version, params = self.store.get()
+                bucket = bucket_for(n, self.buckets)
+                obs, pids = self._pad_rows([r["obs"] for r in reqs],
+                                           [r["id"] for r in reqs], bucket)
+                if spans.enabled():
+                    t = time.perf_counter()
+                    spans.count("serve.rows", n)
+                    spans.count("serve.bucket_rows", bucket)
+                    spans.count("serve.queue_wait_s",
+                                sum(t - r["arrival"] for r in reqs))
+            with spans.span("serve.dispatch"):
+                action, logp, value = self._dispatch(params, obs, pids, n)
+            with spans.span("serve.read_back"):
+                action, logp, value = jax.device_get(
+                    (action[:n], logp[:n], value[:n]))
+            with spans.span("serve.respond"):
+                done = time.perf_counter()
+                out = []
+                for j, r in enumerate(reqs):
+                    resp = {"id": r["id"], "action": action[j],
+                            "logp": float(logp[j]),
+                            "value": float(value[j]), "version": version,
+                            "latency_s": done - r["arrival"]}
+                    self.results[r["id"]] = resp
+                    out.append(resp)
+                return out
 
     def drain(self) -> List[dict]:
         """Serve until the admission queue is empty (ignores arrival

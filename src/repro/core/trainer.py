@@ -312,27 +312,29 @@ class Trainer:
         available, and any extra staleness is structural (the queue
         depth), not sampled."""
         delay = self.cfg.policy_lag if delay is None else delay
-        k_roll, _ = self._iter_key(it)
-        actor = self.agent.actor_policy(state, delay)
-        traj, env_state = rollout(self.agent.policy, actor, self.env,
-                                  k_roll, env_state, self.cfg.unroll)
-        boot_obs = jax.vmap(self.env.obs)(env_state)
+        with jax.named_scope("rollout"):
+            k_roll, _ = self._iter_key(it)
+            actor = self.agent.actor_policy(state, delay)
+            traj, env_state = rollout(self.agent.policy, actor, self.env,
+                                      k_roll, env_state, self.cfg.unroll)
+            boot_obs = jax.vmap(self.env.obs)(env_state)
         return {"traj": traj, "boot": boot_obs}, env_state
 
     def _consume(self, state, ep_run, ep_last, item, it):
         """Learner-consumer half: one learner_step on a queue item plus
         the episode accounting (which must see trajectories in
         consumption order, so it lives on this side of the seam)."""
-        _, k_learn = self._iter_key(it)
-        state, metrics = self.agent.learner_step(
-            state, item["traj"], item["boot"], k_learn,
-            grad_tx=self._grad_tx, param_tx=self._param_tx)
-        ep_run, ep_ret = self._episode_stats(ep_run, ep_last,
-                                             item["traj"])
-        metrics = dict(metrics, episode_return=ep_ret)
-        if self.mesh is not None and self._pmean_axes:
-            metrics = {k: jax.lax.pmean(v, self._pmean_axes)
-                       for k, v in metrics.items()}
+        with jax.named_scope("learner"):
+            _, k_learn = self._iter_key(it)
+            state, metrics = self.agent.learner_step(
+                state, item["traj"], item["boot"], k_learn,
+                grad_tx=self._grad_tx, param_tx=self._param_tx)
+            ep_run, ep_ret = self._episode_stats(ep_run, ep_last,
+                                                 item["traj"])
+            metrics = dict(metrics, episode_return=ep_ret)
+            if self.mesh is not None and self._pmean_axes:
+                metrics = {k: jax.lax.pmean(v, self._pmean_axes)
+                           for k, v in metrics.items()}
         return state, ep_run, ep_ret, metrics
 
     # ---- one training iteration (shared by fused/unfused paths) ------
