@@ -1,5 +1,11 @@
 """Jit'd public wrapper matching the model's (B,S,KVH,G,D) layout.
 
+It transposes to the kernel's (B, H, S, D). A sequence that fits one
+block (S rounded up to 8 is at most bq and bk) runs unpadded on the
+kernel's short grid, a step per bb samples with all heads; a longer one
+is padded to a multiple of bq and runs on the tiled (B, H, nq, nk) grid
+(see kernel.py).
+
 `flash_attention` is differentiable: its forward pass is the Pallas
 kernel, and its backward pass (a `jax.custom_vjp`) recomputes the
 attention through `jax.vjp` of the pure-jnp oracle `attention_ref`, so
@@ -22,11 +28,12 @@ def flash_attention(qg, k, v, *, causal=True, window=0, bq=128, bk=128):
 
 def _flash_fwd_kernel(qg, k, v, causal, window, bq, bk):
     B, S, KVH, G, D = qg.shape
-    # short sequences (the trunk's feature mode attends over a handful
-    # of positions) take one block of S rounded up to a sublane tile,
-    # not a 128-row block that is mostly padding
+    # a sequence that fits one block (the trunk's feature mode attends
+    # over a handful of positions) is the block: no padding
     tile = -(-S // 8) * 8
     bq, bk = min(bq, tile), min(bk, tile)
+    if bq == bk == tile:
+        bq = bk = S
     q = qg.transpose(0, 2, 3, 1, 4).reshape(B, KVH * G, S, D)
     kk = k.transpose(0, 2, 1, 3)
     vv = v.transpose(0, 2, 1, 3)

@@ -98,6 +98,87 @@ def test_flash_custom_vjp_matches_ref_grad(S, causal, window, rng):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 
+def _grouped_inputs(key, B, S, KVH=2, G=2, D=64):
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (B, S, KVH, G, D)),
+            jax.random.normal(ks[1], (B, S, KVH, D)),
+            jax.random.normal(ks[2], (B, S, KVH, D)))
+
+
+@pytest.mark.parametrize("S", [4, 8])
+@pytest.mark.parametrize("B", [1, 4, 16, 256, 300])
+def test_flash_short_sequence_parity(B, S, rng):
+    """The short grid (a step per block of samples, all heads) matches
+    the oracle at the trunk's widths with GQA G=2, causal, including a
+    B (300) that the block of samples does not divide."""
+    from repro.kernels.flash_attention.ref import attention_ref_grouped
+    qg, k, v = _grouped_inputs(rng, B, S)
+    o = flash_attention(qg, k, v, causal=True)
+    r = attention_ref_grouped(qg, k, v, causal=True)
+    np.testing.assert_allclose(o, r, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,S,causal,window", [
+    (7, 5, False, 0),                  # non-causal, S no sublane multiple
+    (9, 8, True, 3),                   # sliding window inside a sample
+    (40, 3, True, 0),
+])
+def test_flash_short_sequence_masks(B, S, causal, window, rng):
+    """The short grid's causal, window and non-causal masks at odd S."""
+    from repro.kernels.flash_attention.ref import attention_ref_grouped
+    qg, k, v = _grouped_inputs(rng, B, S, D=32)
+    o = flash_attention(qg, k, v, causal=causal, window=window)
+    r = attention_ref_grouped(qg, k, v, causal=causal, window=window)
+    np.testing.assert_allclose(o, r, atol=2e-5, rtol=2e-5)
+
+
+def test_flash_short_grid_masks_padded_keys(rng):
+    """`valid_len` on the short grid: keys past it, zero-padded by the
+    caller, are never attended."""
+    ks = jax.random.split(rng, 3)
+    q = jax.random.normal(ks[0], (3, 4, 5, 32))
+    k = jax.random.normal(ks[1], (3, 2, 5, 32))
+    v = jax.random.normal(ks[2], (3, 2, 5, 32))
+    pad = ((0, 0), (0, 0), (0, 3), (0, 0))
+    o = flash_attention_hsd(jnp.pad(q, pad), jnp.pad(k, pad),
+                            jnp.pad(v, pad), causal=False, valid_len=5)
+    r = attention_ref(q, k, v, causal=False)
+    np.testing.assert_allclose(o[:, :, :5], r, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,S", [(256, 4)])
+def test_flash_short_custom_vjp(B, S, rng):
+    """Value and gradients through the custom VJP on the short grid."""
+    from repro.kernels.flash_attention.ref import attention_ref_grouped
+    qg, k, v = _grouped_inputs(rng, B, S)
+    ct = jax.random.normal(jax.random.fold_in(rng, 1), qg.shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a, causal=True) * ct)
+
+    l1, g1 = jax.value_and_grad(loss(flash_attention), (0, 1, 2))(qg, k, v)
+    l2, g2 = jax.value_and_grad(loss(attention_ref_grouped),
+                                (0, 1, 2))(qg, k, v)
+    np.testing.assert_allclose(l1, l2, rtol=1e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,S,grid,bb", [
+    (1, 4, (1,), 1),                   # serving buckets: one step
+    (16, 4, (1,), 16),
+    (256, 4, (2,), 128),               # rollout
+    (8192, 4, (64,), 128),             # learner forward
+    (8, 512, (8, 4, 4, 4), None),      # long S keeps the tiled grid
+])
+def test_flash_plan_picks_grid(B, S, grid, bb):
+    """The grid follows the shapes alone: trunk shapes (S=4, H=4, KVH=2,
+    D=64, float32) step over blocks of samples, long S over (B, H, nq,
+    nk)."""
+    from repro.kernels.flash_attention.kernel import plan
+    assert plan(B, 4, 2, S, 64) == (grid, bb)
+
+
 @pytest.mark.parametrize("B,S,KVH,G,D,causal,window", [
     (2, 128, 2, 2, 32, True, 0),
     (1, 256, 1, 4, 64, True, 64),      # sliding window, MQA kv=1

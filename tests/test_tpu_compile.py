@@ -74,7 +74,8 @@ def _assert_kernel(hlo):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("B,S", [(8192, 4), (8, 512)])
+@pytest.mark.parametrize("B,S", [(8192, 4), (8, 512), (256, 4), (16, 4),
+                                 (1, 4)])
 def test_flash_forward_compiles(compile_tpu, B, S):
     from repro.kernels.flash_attention.ops import flash_attention
     G = H // KVH
@@ -83,7 +84,8 @@ def test_flash_forward_compiles(compile_tpu, B, S):
         ((B, S, KVH, D), F32)))
 
 
-@pytest.mark.parametrize("B,S", [(8192, 4), (8, 512)])
+@pytest.mark.parametrize("B,S", [(8192, 4), (8, 512), (256, 4), (16, 4),
+                                 (1, 4)])
 def test_flash_grad_compiles(compile_tpu, B, S):
     from repro.kernels.flash_attention.ops import flash_attention
 
