@@ -17,7 +17,7 @@ def run():
         ("table2/parallel_features", None,
          "zero-copy batch simulation (vmap+scan);pjit/shard_map "
          "(pod,data,model) mesh;ZeRO-3 FSDP;expert parallelism;"
-         "Pallas TPU kernels (flash-attn, wkv6, gmm, vtrace)"),
+         "Pallas TPU kernels (flash-attn, wkv6, vtrace)"),
         ("table2/scale_proven", None,
          "512-chip multi-pod dry-run; 40 (arch x shape) baselines"),
     ]

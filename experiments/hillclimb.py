@@ -19,7 +19,6 @@ def main():
     ap.add_argument("--no-fsdp", dest="fsdp", action="store_false")
     ap.add_argument("--param-dtype", default=None)
     ap.add_argument("--remat", default=None, choices=("on", "off"))
-    ap.add_argument("--moe-local", action="store_true")
     ap.add_argument("--act-shard", action="store_true",
                     help="with_sharding_constraint on the scan carry")
     ap.add_argument("--dtype", default="bfloat16")
@@ -31,11 +30,10 @@ def main():
 
     arch, shape = args.pair.split(":")
     opts = None
-    if args.remat is not None or args.moe_local or args.act_shard:
+    if args.remat is not None or args.act_shard:
         axes = ("data",) if args.act_shard else ()
         opts = ModelOpts(dtype=args.dtype,
                          remat=(args.remat or "on") == "on",
-                         moe_local_dispatch=args.moe_local,
                          act_batch_axes=axes)
     mesh_shape = (tuple(int(x) for x in args.mesh_shape.split(","))
                   if args.mesh_shape else None)

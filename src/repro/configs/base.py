@@ -20,21 +20,39 @@ ATTN_LOCAL = "attn_local"  # sliding-window attention
 MLA = "mla"              # multi-head latent attention (MiniCPM3 / DeepSeek-V2 style)
 RWKV = "rwkv6"           # RWKV-6 "Finch" token-mix block (attention-free)
 MAMBA = "mamba"          # Mamba selective-SSM block
+CONV = "conv"            # gated short convolution (LFM2)
 
-SUBQUADRATIC = frozenset({ATTN_LOCAL, RWKV, MAMBA})
+SUBQUADRATIC = frozenset({ATTN_LOCAL, RWKV, MAMBA, CONV})
 
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    """Mixture-of-experts FFN spec."""
+    """Mixture-of-experts FFN spec.
+
+    The router scores all `n_experts` and picks `top_k`; a layer holds
+    the experts `held` = (first, stop) of them (None: all), its share
+    under expert parallelism, and computes only their part of the
+    result. `expert_bias` adds a constant per-expert bias to the scores
+    when choosing (not when weighting) the experts."""
     n_experts: int
     top_k: int
     d_ff: int                      # per-expert hidden dim
     n_shared: int = 0              # always-on shared experts (DeepSeek-MoE)
     every: int = 1                 # MoE FFN every `every` layers (llama4 alternates)
     first_dense: int = 0           # leading dense layers (DeepSeek-MoE layer 0)
-    capacity_factor: float = 1.25
     aux_loss_coef: float = 0.01
+    score: str = "softmax"         # softmax | sigmoid
+    expert_bias: bool = False
+    routed_scale: float = 1.0      # times the normalised top-k weights
+    held: Optional[Tuple[int, int]] = None
+
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range()
+        return hi - lo
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +77,8 @@ class ModelConfig:
     ssm_state: int = 16            # mamba state dim per channel
     ssm_conv: int = 4
     ssm_expand: int = 2
+    # gated short convolution (CONV)
+    conv_cache: int = 3            # kernel length L; decode keeps L - 1 inputs
     # encoder-decoder (whisper)
     enc_layers: int = 0
     enc_tokens: int = 0            # encoder sequence length (stub frontend output)
@@ -68,6 +88,8 @@ class ModelConfig:
     frontend_dim: int = 0          # stub embedding dim (0 -> d_model)
     # misc
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6
+    qk_norm: bool = False          # per-head RMSNorm of q and k before rope
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     source: str = ""               # citation
@@ -116,7 +138,7 @@ class ModelConfig:
                 q = d * self.n_heads * hd
                 kv = 2 * d * self.n_kv_heads * hd
                 o = self.n_heads * hd * d
-                total += q + kv + o
+                total += q + kv + o + (2 * hd if self.qk_norm else 0)
             elif kind == MLA:
                 rq = self.q_lora_rank or d
                 total += d * rq + rq * self.n_heads * (hd + self.rope_head_dim)
@@ -132,20 +154,38 @@ class ModelConfig:
                 total += di * self.ssm_conv          # conv
                 total += di * (2 * self.ssm_state)   # B,C proj (x-dependent)
                 total += di * 2                      # dt proj (rank-1 approx) + A,D
+            elif kind == CONV:
+                total += 4 * d * d + self.conv_cache * d  # in/out proj, conv
             # channel mixer (FFN) — every block has one except RWKV's
             # built-in channel-mix
             if kind in (RWKV,):
                 total += 2 * d * int(self.d_ff) + d * d  # k,v + receptance
             elif self.is_moe_layer(i):
                 m = self.moe
-                e = (m.top_k if active_only else m.n_experts) + m.n_shared
+                e = (m.top_k if active_only else m.n_held) + m.n_shared
                 total += e * 3 * d * m.d_ff + d * m.n_experts  # router
+                total += m.n_experts if m.expert_bias else 0
             else:
                 total += 3 * d * self.d_ff  # swiglu
         # encoder (whisper): same-dim encoder layers, full attn + mlp
         for _ in range(self.enc_layers):
             total += 4 * d * d + 3 * d * self.d_ff
         return int(total)
+
+    # -- one chip's share of a deployment -------------------------------
+    def cut(self, n_layers: int = 0, vocab: int = 0,
+            experts_held: Optional[Tuple[int, int]] = None
+            ) -> "ModelConfig":
+        """The part of the model one chip holds: the first `n_layers`
+        (a pipeline stage), `vocab` rows of the vocabulary, and of each
+        expert layer the experts `experts_held` = (first, stop) (expert
+        parallelism; the router keeps all its outputs). 0 / None keeps
+        a size as it is. Every width stays."""
+        moe = self.moe
+        if experts_held is not None:
+            moe = dataclasses.replace(moe, held=tuple(experts_held))
+        return dataclasses.replace(self, n_layers=n_layers or self.n_layers,
+                                   vocab=vocab or self.vocab, moe=moe)
 
     # -- reduced variant for CPU smoke tests ----------------------------
     def reduced(self) -> "ModelConfig":
@@ -158,9 +198,10 @@ class ModelConfig:
             moe = dataclasses.replace(
                 self.moe, n_experts=4, top_k=min(self.moe.top_k, 2),
                 d_ff=64, n_shared=min(self.moe.n_shared, 1),
-                first_dense=min(self.moe.first_dense, 1))
-        # keep at least one full super-block of the pattern
-        n_layers = max(2, len(self.layer_pattern))
+                first_dense=min(self.moe.first_dense, 1), held=None)
+        # keep at least one full super-block of the pattern (a published
+        # per-layer list, as LFM2's, keeps its first 8 layers)
+        n_layers = max(2, min(len(self.layer_pattern), 8))
         return dataclasses.replace(
             self, n_layers=n_layers, d_model=d, n_heads=n_heads,
             n_kv_heads=kv, head_dim=hd, d_ff=128, vocab=512, moe=moe,

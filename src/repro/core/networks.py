@@ -126,16 +126,22 @@ class TrunkPolicy:
         token table — so box-observation envs (cartpole, pendulum)
         train the same transformer trunk.
     Discrete heads emit logits; continuous heads reuse MLPPolicy's
-    tanh-gaussian squashed into `act_mid ± act_scale`."""
+    tanh-gaussian squashed into `act_mid ± act_scale`. `n_layers`,
+    `vocab` and `experts_held` cut the architecture to one chip's
+    share of a deployment (`ModelConfig.cut`)."""
 
     def __init__(self, arch="paper-drl-trunk", n_actions=4, ctx=8,
                  reduced=True, obs_dim=None, act_dim=1, act_mid=0.0,
-                 act_scale=1.0, use_kernels=False):
+                 act_scale=1.0, use_kernels=False, n_layers=0, vocab=0,
+                 experts_held=None):
+        from repro.configs import ModelConfig, get_config
         from repro.models import build_model
         from repro.models.model import ModelOpts
-        self.lm = build_model(arch, ModelOpts(dtype="float32", remat=False,
-                                              use_kernels=use_kernels),
-                              reduced=reduced)
+        cfg = arch if isinstance(arch, ModelConfig) else get_config(arch)
+        cfg = (cfg.reduced() if reduced else cfg).cut(n_layers, vocab,
+                                                      experts_held)
+        self.lm = build_model(cfg, ModelOpts(dtype="float32", remat=False,
+                                             use_kernels=use_kernels))
         self.n_actions = n_actions
         self.discrete = n_actions > 0
         self.features = obs_dim          # None => token-obs mode
@@ -147,12 +153,13 @@ class TrunkPolicy:
 
     @classmethod
     def for_spec(cls, spec, arch="paper-drl-trunk", reduced=True,
-                 use_kernels=True):
+                 use_kernels=True, n_layers=0, vocab=0, experts_held=None):
         """Build a trunk policy matching an EnvSpec: integer obs run in
         token mode, float obs in feature mode; head width and continuous
         action bounds read off the spec (mirrors MLPPolicy.for_spec)."""
         a, o = spec.action, spec.observation
-        kw = dict(arch=arch, reduced=reduced, use_kernels=use_kernels)
+        kw = dict(arch=arch, reduced=reduced, use_kernels=use_kernels,
+                  n_layers=n_layers, vocab=vocab, experts_held=experts_held)
         if jnp.issubdtype(jnp.dtype(o.dtype), jnp.integer):
             kw["ctx"] = spec.obs_dim
         else:
@@ -192,7 +199,8 @@ class TrunkPolicy:
             x = (obs.astype(jnp.float32)[..., None]
                  * params["feat"]["w"] + params["feat"]["b"])  # (B, F, d)
         x, _, _ = self.lm._run_seq(params["lm"], x, jnp.int32(0), None, 0)
-        h = apply_norm(params["lm"]["final_norm"], x)[:, -1]
+        h = apply_norm(params["lm"]["final_norm"], x,
+                       self.lm.cfg.norm_eps)[:, -1]
         pi = h @ params["pi"]["w"] + params["pi"]["b"]
         v = (h @ params["v"]["w"] + params["v"]["b"])[..., 0]
         if squeeze:
@@ -245,7 +253,7 @@ def make_policy(spec, policy="mlp", hidden=(64, 64), **trunk_kwargs):
     """Policy factory shared by the algorithm registry: `policy="mlp"`
     (the house actor-critic MLP, `hidden` widths) or `policy="trunk"`
     (the transformer trunk via TrunkPolicy.for_spec; `trunk_kwargs`
-    forwards arch/reduced/use_kernels)."""
+    forwards arch/reduced/use_kernels and the cut)."""
     if policy == "trunk":
         return TrunkPolicy.for_spec(spec, **trunk_kwargs)
     if policy != "mlp":

@@ -20,17 +20,16 @@ legal), and scores each head with one batched product over the bb
 samples. A plain masked softmax replaces the online one: there is one
 kv block. On the tiled grid such a sequence would cost a grid step
 (~0.6 us on v5e) per sample and head, however little each step does.
-The last block may run past B: its rows there hold stale VMEM and are
-never written back, and as each sample is its own batch element of the
-products they reach no live row.
 
-bb is the most samples whose blocks fit SHORT_VMEM_BYTES: q and o at H
-heads, k and v at KVH heads, each (S, D) slab counted in whole (8, 128)
-tiles of 4-byte types ((16, 128) for 2-byte; a (4, 64) float32 slab as
-4 KiB, not 1 KiB) and every block double-buffered by the pipeline. That
-keeps a quarter of v5e's 16 MiB default scoped VMEM for the scores and
-products; at S=4, H=4, KVH=2, D=64 float32, bb = 128, and the serving
-buckets (B = 1, 4, 16) take one step.
+bb is the most samples that divide B and whose blocks fit
+SHORT_VMEM_BYTES: q and o at H heads, k and v at KVH heads, each (S, D)
+slab counted in whole (8, 128) tiles of 4-byte types ((16, 128) for
+2-byte; a (4, 64) float32 slab as 4 KiB, not 1 KiB) and every block
+double-buffered by the pipeline. That keeps a quarter of v5e's 16 MiB
+default scoped VMEM for the scores and products; at S=4, H=4, KVH=2,
+D=64 float32, bb = 128, and the serving buckets (B = 1, 4, 16) take one
+step; at H=32, KVH=8 (LFM2) bb = 16 for B = 32 and 1,024. No block runs
+past B, so no step reads or writes outside the arrays.
 """
 import functools
 
@@ -54,8 +53,9 @@ def plan(B, H, KVH, S, D, *, bq=128, bk=128, itemsize=4):
     sublanes = 32 // itemsize  # (8, 128) tiles for 4-byte types
     slab = -(-S // sublanes) * sublanes * (-(-D // 128) * 128) * itemsize
     per_sample = 2 * (2 * H + 2 * KVH) * slab  # double-buffered
-    bb = min(B, max(1, SHORT_VMEM_BYTES // per_sample))
-    return (-(-B // bb),), bb
+    cap = max(1, SHORT_VMEM_BYTES // per_sample)
+    bb = max(n for n in range(1, min(B, cap) + 1) if B % n == 0)
+    return (B // bb,), bb
 
 
 def _kernel(qref, kref, vref, oref, mref, lref, accref, *,

@@ -13,7 +13,7 @@ All counts are GLOBAL (whole step, all chips); dryrun divides by chips.
 """
 from __future__ import annotations
 
-from repro.configs.base import (ATTN, ATTN_LOCAL, MLA, RWKV, MAMBA,
+from repro.configs.base import (ATTN, ATTN_LOCAL, MLA, RWKV, MAMBA, CONV,
                                 ModelConfig, ShapeConfig)
 
 WKV_CHUNK = 64
@@ -63,6 +63,12 @@ def _mamba_flops_token(cfg):
     return proj + conv + bc + scan
 
 
+def _conv_flops_token(cfg):
+    """Gated short conv: in (d -> 3d) and out projections, the taps."""
+    d = cfg.d_model
+    return 2 * d * 3 * d + 2 * d * d + 2 * cfg.conv_cache * d
+
+
 def _ffn_flops_token(cfg, layer_idx):
     d = cfg.d_model
     if cfg.is_moe_layer(layer_idx):
@@ -89,6 +95,8 @@ def fwd_flops_per_token(cfg: ModelConfig, ctx: float,
             continue                                   # ffn built-in
         elif kind == MAMBA:
             total += _mamba_flops_token(cfg)
+        elif kind == CONV:
+            total += _conv_flops_token(cfg)
         total += _ffn_flops_token(cfg, i)
     return total
 
@@ -148,6 +156,8 @@ def cache_bytes(cfg: ModelConfig, shape: ShapeConfig, act_bytes=2) -> float:
             di = cfg.ssm_expand * cfg.d_model
             total += B * di * cfg.ssm_state * 4 \
                 + B * (cfg.ssm_conv - 1) * di * act_bytes
+        elif kind == CONV:
+            total += B * (cfg.conv_cache - 1) * cfg.d_model * act_bytes
     if cfg.enc_layers:
         total += cfg.n_layers * B * cfg.enc_tokens * cfg.n_kv_heads \
             * cfg.head_dim * 2 * act_bytes

@@ -32,7 +32,6 @@ class AttnOpts:
     block_k: int = 512       # kv block for online softmax
     n_q_chunks: int = 8      # static causal query chunks
     use_kernels: bool = False  # route seq attention through Pallas
-    moe_local: bool = False    # row-local MoE dispatch (see models/moe.py)
 
 
 # ---------------------------------------------------------------------------
@@ -59,12 +58,16 @@ def init_attn(cfg, key, kind: str):
             "wo": dense_init(ks[7], (cfg.n_heads, hd, cfg.d_model)),
         }
         return p
-    return {
+    p = {
         "wq": dense_init(ks[0], (cfg.d_model, cfg.n_heads, hd)),
         "wk": dense_init(ks[1], (cfg.d_model, cfg.n_kv_heads, hd)),
         "wv": dense_init(ks[2], (cfg.d_model, cfg.n_kv_heads, hd)),
         "wo": dense_init(ks[3], (cfg.n_heads, hd, cfg.d_model)),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = {"scale": jnp.ones((hd,), jnp.float32)}
+        p["k_norm"] = {"scale": jnp.ones((hd,), jnp.float32)}
+    return p
 
 
 def init_cross_attn(cfg, key):
@@ -223,10 +226,15 @@ def local_attention(q, k, v, pos0, *, window: int):
 # ---------------------------------------------------------------------------
 
 def _qkv(cfg, p, x):
+    """Projections, with each head's q and k RMS-normed (before rope)
+    where the config has q/k norm."""
     dt = x.dtype
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
+    if "q_norm" in p:
+        q = apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = apply_norm(p["k_norm"], k, cfg.norm_eps)
     return q, k, v
 
 

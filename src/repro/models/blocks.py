@@ -1,4 +1,4 @@
-"""Per-layer block: token mixer (attn/local/MLA/RWKV/Mamba) + channel
+"""Per-layer block: token mixer (attn/local/MLA/RWKV/Mamba/conv) + channel
 mixer (dense SwiGLU or MoE), pre-norm residual. Whisper decoder blocks add
 cross-attention. One entry point per execution mode."""
 from __future__ import annotations
@@ -6,11 +6,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, ATTN_LOCAL, MLA, RWKV, MAMBA
+from repro.configs.base import ATTN, ATTN_LOCAL, MLA, RWKV, MAMBA, CONV
 from repro.models import attention as attn
 from repro.models import mamba as mamba_mod
 from repro.models import moe as moe_mod
 from repro.models import rwkv6 as rwkv_mod
+from repro.models import shortconv
 from repro.models.layers import (init_norm, apply_norm, init_mlp, apply_mlp,
                                  init_mlp_gelu, apply_mlp_gelu)
 
@@ -29,6 +30,8 @@ def init_block(cfg, key, kind: str, is_moe: bool, has_cross: bool = False,
         return p  # rwkv channel-mix params live inside the mixer
     elif kind == MAMBA:
         p["mixer"] = mamba_mod.init_mamba(cfg, ks[0])
+    elif kind == CONV:
+        p["mixer"] = shortconv.init_conv(cfg, ks[0])
     else:
         raise ValueError(kind)
     if has_cross:
@@ -63,6 +66,8 @@ def init_cache(cfg, kind: str, batch: int, capacity: int, dtype,
         di = cfg.ssm_expand * cfg.d_model
         c = {"conv": jnp.zeros((batch, cfg.ssm_conv - 1, di), dtype),
              "ssm": jnp.zeros((batch, di, cfg.ssm_state), jnp.float32)}
+    elif kind == CONV:
+        c = {"conv": shortconv.init_state(cfg, batch, dtype)}
     else:
         raise ValueError(kind)
     if has_cross:
@@ -87,13 +92,14 @@ def apply_block_seq(cfg, p, kind, is_moe, x, pos0, opts, *,
     B = x.shape[0]
     aux = jnp.zeros((), jnp.float32)
     cache = {}
-    h = apply_norm(p["norm1"], x)
+    eps = cfg.norm_eps
+    h = apply_norm(p["norm1"], x, eps)
     if kind == RWKV:
         st = cache_in or init_cache(cfg, RWKV, B, 0, x.dtype)
         o, tm = rwkv_mod.rwkv_time_mix_seq(
             cfg, p["mixer"], h, {"S": st["S"], "shift": st["shift_tm"]})
         x = x + o
-        h2 = apply_norm(p["norm2"], x)
+        h2 = apply_norm(p["norm2"], x, eps)
         o2, shift_cm = rwkv_mod.rwkv_channel_mix(cfg, p["mixer"], h2,
                                                  st["shift_cm"])
         x = x + o2
@@ -106,6 +112,11 @@ def apply_block_seq(cfg, p, kind, is_moe, x, pos0, opts, *,
         o, new_st = mamba_mod.mamba_seq(cfg, p["mixer"], h, st)
         if cache_capacity:
             cache.update(new_st)
+    elif kind == CONV:
+        o, st = shortconv.conv_seq(cfg, p["mixer"], h,
+                                   cache_in["conv"] if cache_in else None)
+        if cache_capacity:
+            cache["conv"] = st
     elif kind == MLA:
         o, c = attn.mla_seq(cfg, p["mixer"], h, pos0, opts,
                             cache_capacity=cache_capacity)
@@ -118,18 +129,16 @@ def apply_block_seq(cfg, p, kind, is_moe, x, pos0, opts, *,
             cache.update(c)
     x = x + o
     if enc_out is not None and "xattn" in p:
-        hx = apply_norm(p["xnorm"], x)
+        hx = apply_norm(p["xnorm"], x, eps)
         ek, ev = _cross_kv(cfg, p, enc_out)
         ox, _ = attn.gqa_seq(cfg, p["xattn"], hx, pos0, ATTN, opts,
                              cross_kv=(ek, ev))
         x = x + ox
         if cache_capacity:
             cache["ek"], cache["ev"] = ek, ev
-    h2 = apply_norm(p["norm2"], x)
+    h2 = apply_norm(p["norm2"], x, eps)
     if is_moe:
-        o2, aux = moe_mod.apply_moe(cfg, p["ffn"], h2,
-                                    use_kernels=opts.use_kernels,
-                                    local_dispatch=opts.moe_local)
+        o2, aux = moe_mod.apply_moe(cfg, p["ffn"], h2)
     elif gelu_mlp:
         o2 = apply_mlp_gelu(p["ffn"], h2)
     else:
@@ -141,14 +150,15 @@ def apply_block_decode(cfg, p, kind, is_moe, x, cache, pos, opts,
                        gelu_mlp=False):
     """One-token decode. Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(p["norm1"], x)
+    eps = cfg.norm_eps
+    h = apply_norm(p["norm1"], x, eps)
     new_cache = dict(cache)
     if kind == RWKV:
         o, tm = rwkv_mod.rwkv_time_mix_seq(
             cfg, p["mixer"], h, {"S": cache["S"],
                                  "shift": cache["shift_tm"]}, chunk=1)
         x = x + o
-        h2 = apply_norm(p["norm2"], x)
+        h2 = apply_norm(p["norm2"], x, eps)
         o2, shift_cm = rwkv_mod.rwkv_channel_mix(cfg, p["mixer"], h2,
                                                  cache["shift_cm"])
         new_cache = {"S": tm["S"], "shift_tm": tm["shift"],
@@ -159,6 +169,9 @@ def apply_block_decode(cfg, p, kind, is_moe, x, cache, pos, opts,
             cfg, p["mixer"], h, {"conv": cache["conv"],
                                  "ssm": cache["ssm"]})
         new_cache.update(st)
+    elif kind == CONV:
+        o, new_cache["conv"] = shortconv.conv_seq(cfg, p["mixer"], h,
+                                                  cache["conv"])
     elif kind == MLA:
         o, c = attn.mla_decode(cfg, p["mixer"], h,
                                {"ckv": cache["ckv"], "kr": cache["kr"]},
@@ -171,15 +184,13 @@ def apply_block_decode(cfg, p, kind, is_moe, x, cache, pos, opts,
         new_cache.update(c)
     x = x + o
     if "xattn" in p and "ek" in cache:
-        hx = apply_norm(p["xnorm"], x)
+        hx = apply_norm(p["xnorm"], x, eps)
         ox, _ = attn.gqa_decode(cfg, p["xattn"], hx, None, pos, ATTN, opts,
                                 cross_kv=(cache["ek"], cache["ev"]))
         x = x + ox
-    h2 = apply_norm(p["norm2"], x)
+    h2 = apply_norm(p["norm2"], x, eps)
     if is_moe:
-        o2, aux = moe_mod.apply_moe(cfg, p["ffn"], h2,
-                                    use_kernels=opts.use_kernels,
-                                    local_dispatch=opts.moe_local)
+        o2, aux = moe_mod.apply_moe(cfg, p["ffn"], h2)
     elif gelu_mlp:
         o2 = apply_mlp_gelu(p["ffn"], h2)
     else:

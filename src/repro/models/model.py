@@ -57,7 +57,6 @@ class ModelOpts:
     use_kernels: bool = False
     block_k: int = 512
     n_q_chunks: int = 8
-    moe_local_dispatch: bool = False
     # mesh axes the batch dim of activations is sharded over; when set,
     # a with_sharding_constraint re-anchors the (B,S,d) carry inside the
     # layer scan — XLA otherwise loses the sharding in the rematted
@@ -79,8 +78,7 @@ class LanguageModel:
         self.opts = opts
         self.attn_opts = AttnOpts(dtype=opts.jdtype, block_k=opts.block_k,
                                   n_q_chunks=opts.n_q_chunks,
-                                  use_kernels=opts.use_kernels,
-                                  moe_local=opts.moe_local_dispatch)
+                                  use_kernels=opts.use_kernels)
         self.gelu_mlp = cfg.family == "audio"
         self.has_cross = cfg.enc_layers > 0
         pat = cfg.pattern()
@@ -159,7 +157,7 @@ class LanguageModel:
             return y, None
 
         x, _ = jax.lax.scan(body, x, params["enc"]["stack"])
-        return apply_norm(params["enc"]["final_norm"], x)
+        return apply_norm(params["enc"]["final_norm"], x, cfg.norm_eps)
 
     # ------------------------------------------------------ seq runner
     def _run_seq(self, params, x, pos0, enc_out, cache_capacity):
@@ -260,7 +258,7 @@ class LanguageModel:
         if cfg.frontend == "vision_stub":
             x, n_prefix = self._prepend_frontend(params, x, frontend)
         x, _, aux = self._run_seq(params, x, jnp.int32(0), enc_out, 0)
-        x = apply_norm(params["final_norm"], x)
+        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = unembed(params["embed"], x, cfg)
         if n_prefix:
             logits = logits[:, n_prefix:]
@@ -296,7 +294,7 @@ class LanguageModel:
             x, n_prefix = self._prepend_frontend(params, x, frontend)
             cap = cap + n_prefix
         x, caches, _ = self._run_seq(params, x, jnp.int32(0), enc_out, cap)
-        x = apply_norm(params["final_norm"], x[:, -1:])
+        x = apply_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
         logits = unembed(params["embed"], x, cfg)
         return logits, caches
 
@@ -347,7 +345,7 @@ class LanguageModel:
                 tc.append(c)
             new_cache["tail"] = tc
 
-        x = apply_norm(params["final_norm"], x)
+        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = unembed(params["embed"], x, cfg)
         return logits, new_cache
 

@@ -10,9 +10,8 @@ from repro.kernels.advantages.ref import (discounted_return_ref, gae_ref,
                                           nstep_return_ref)
 from repro.kernels.flash_attention.kernel import flash_attention_hsd
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.models.moe import moe_gmm
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.gmm.ops import gmm
-from repro.kernels.gmm.ref import gmm_ref
 from repro.kernels.replay_sample.ops import prioritized_sample
 from repro.kernels.replay_sample.ref import (prioritized_sample_ref,
                                              prioritized_weights_ref,
@@ -179,6 +178,15 @@ def test_flash_plan_picks_grid(B, S, grid, bb):
     assert plan(B, 4, 2, S, 64) == (grid, bb)
 
 
+@pytest.mark.parametrize("B,bb", [(32, 16), (1024, 16), (300, 15),
+                                  (19, 19)])
+def test_flash_plan_blocks_divide_batch(B, bb):
+    """LFM2's heads (H=32, KVH=8) fit 19 samples a step; the step takes
+    the most that divide B, so no block runs past the batch."""
+    from repro.kernels.flash_attention.kernel import plan
+    assert plan(B, 32, 8, 4, 64) == ((B // bb,), bb)
+
+
 @pytest.mark.parametrize("B,S,KVH,G,D,causal,window", [
     (2, 128, 2, 2, 32, True, 0),
     (1, 256, 1, 4, 64, True, 64),      # sliding window, MQA kv=1
@@ -248,26 +256,71 @@ def test_wkv6_model_chunked_matches_ref(rng):
 
 
 # ----------------------------------------------------------------- gmm
-@pytest.mark.parametrize("E,C,d,f", [
-    (4, 70, 96, 130),                  # padding on every axis
-    (2, 128, 128, 128),                # exact tiles
-    (8, 16, 512, 64),
+def _gmm_loop(x, w, sizes):
+    """Plain per-group products: rows of group g times w[g], then zeros."""
+    out, r = np.zeros((x.shape[0], w.shape[-1]), np.float64), 0
+    for g, n in enumerate(sizes):
+        out[r:r + n] = np.asarray(x[r:r + n], np.float64) @ np.asarray(
+            w[g], np.float64)
+        r += n
+    return out
+
+
+@pytest.mark.parametrize("sizes,d,f", [
+    ((3, 0, 20, 5, 12), 96, 130),      # an empty group, odd widths
+    ((128, 128), 128, 128),
+    ((1, 2, 3, 4, 5, 6, 7, 8), 512, 64),
 ])
-def test_gmm_sweep(E, C, d, f, rng):
-    x = jax.random.normal(rng, (E, C, d))
-    w = jax.random.normal(jax.random.fold_in(rng, 1), (E, d, f))
-    np.testing.assert_allclose(gmm(x, w), gmm_ref(x, w),
-                               atol=3e-4, rtol=1e-4)
+def test_gmm_sweep(sizes, d, f, rng):
+    """The experts' grouped matmul over ragged groups (and rows past
+    them, which give zeros) against plain per-group products."""
+    M = sum(sizes) + 7
+    x = jax.random.normal(rng, (M, d))
+    w = jax.random.normal(jax.random.fold_in(rng, 1), (len(sizes), d, f))
+    o = moe_gmm(x, w, jnp.asarray(sizes, jnp.int32))
+    np.testing.assert_allclose(o, _gmm_loop(x, w, sizes), atol=3e-4,
+                               rtol=1e-4)
+    assert not np.any(np.asarray(o[sum(sizes):]))
 
 
 def test_gmm_bf16(rng):
-    x = jax.random.normal(rng, (2, 64, 64)).astype(jnp.bfloat16)
+    x = jax.random.normal(rng, (64, 64)).astype(jnp.bfloat16)
     w = jax.random.normal(jax.random.fold_in(rng, 1), (2, 64, 64))
-    o = gmm(x, w)
+    o = moe_gmm(x, w, jnp.asarray([40, 24], jnp.int32))
     assert o.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(gmm_ref(x, w), np.float32),
-                               atol=0.2, rtol=0.05)
+                               _gmm_loop(x.astype(jnp.float32), w,
+                                         (40, 24)), atol=0.2, rtol=0.05)
+
+
+@pytest.mark.parametrize("sizes", [(64, 0, 0, 0), (0, 0, 0, 0),
+                                   (16, 16, 16, 16)])
+def test_gmm_skewed_groups(sizes, rng):
+    """Every row in one group, no row in any, or an even split."""
+    x = jax.random.normal(rng, (64, 128))
+    w = jax.random.normal(jax.random.fold_in(rng, 1), (4, 128, 128))
+    np.testing.assert_allclose(moe_gmm(x, w, jnp.asarray(sizes, jnp.int32)),
+                               _gmm_loop(x, w, sizes), atol=3e-4, rtol=1e-4)
+
+
+def test_gmm_grad(rng):
+    """Gradients of rows and weights against the per-group products'."""
+    x = jax.random.normal(rng, (40, 64))
+    w = jax.random.normal(jax.random.fold_in(rng, 1), (3, 64, 128))
+    sizes = (10, 0, 25)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def dense(x, w):   # the same products as one masked einsum per group
+        g = jnp.repeat(jnp.arange(3), jnp.asarray(sizes),
+                       total_repeat_length=35)
+        y = jnp.einsum("md,mdf->mf", x[:35], w[g])
+        return jnp.concatenate([y, jnp.zeros((5, 128))])
+
+    got = jax.grad(lambda x, w: jnp.sum(jnp.sin(moe_gmm(x, w, gs))),
+                   (0, 1))(x, w)
+    want = jax.grad(lambda x, w: jnp.sum(jnp.sin(dense(x, w))), (0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
 # --------------------------------------------------------------- vtrace

@@ -67,30 +67,12 @@ def test_moe_sort_dispatch_matches_dense_oracle(rng):
     from repro.models.moe import init_moe, apply_moe, \
         apply_moe_dense_oracle
     cfg = get_config("deepseek-moe-16b").reduced()
-    cfg = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     p = init_moe(cfg, rng)
     x = jax.random.normal(jax.random.fold_in(rng, 1), (2, 24, cfg.d_model))
     out, aux = apply_moe(cfg, p, x)
     ref = apply_moe_dense_oracle(cfg, p, x)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=1e-4)
     assert float(aux) > 0
-
-
-def test_moe_capacity_drops_tokens(rng):
-    """With capacity_factor << 1 the dispatch must drop tokens (outputs
-    differ from the dense oracle) but stay finite."""
-    from repro.models.moe import init_moe, apply_moe, \
-        apply_moe_dense_oracle
-    cfg = get_config("llama4-maverick-400b-a17b").reduced()
-    cfg = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=0.3))
-    p = init_moe(cfg, rng)
-    x = jax.random.normal(jax.random.fold_in(rng, 1), (2, 32, cfg.d_model))
-    out, _ = apply_moe(cfg, p, x)
-    ref = apply_moe_dense_oracle(cfg, p, x)
-    assert bool(jnp.all(jnp.isfinite(out)))
-    assert not bool(jnp.allclose(out, ref, atol=1e-5))
 
 
 # ---------------------------------------------------------------- mamba
@@ -137,18 +119,3 @@ def test_rwkv_decode_chain_matches_seq(rng):
         ys.append(y_t)
     np.testing.assert_allclose(jnp.concatenate(ys, 1), y_all, atol=2e-4,
                                rtol=1e-3)
-
-
-def test_moe_local_dispatch_matches_oracle(rng):
-    """Row-local dispatch (§Perf optimization) is math-identical to the
-    dense oracle when capacity is ample."""
-    from repro.models.moe import init_moe, apply_moe, \
-        apply_moe_dense_oracle
-    cfg = get_config("deepseek-moe-16b").reduced()
-    cfg = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
-    p = init_moe(cfg, rng)
-    x = jax.random.normal(jax.random.fold_in(rng, 2), (3, 24, cfg.d_model))
-    out, aux = apply_moe(cfg, p, x, local_dispatch=True)
-    ref = apply_moe_dense_oracle(cfg, p, x)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=1e-4)

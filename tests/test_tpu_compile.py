@@ -24,6 +24,10 @@ KERNEL_MODULES = ("advantages", "flash_attention", "replay_sample",
 # paper-drl-trunk attention widths (configs/paper_drl.py)
 H, KVH, D = 4, 2, 64
 REPLAY_C, REPLAY_N = 2 ** 20, 512      # Nature-DQN replay capacity
+# LFM2-8B-A1B experts (configs/lfm2_8b_a1b.py): one chip's 8, hidden 2048,
+# expert width 1792; rows = tokens x top-4 (the learn cell's rollout 128
+# tokens, learner 4,096)
+GMM_G, GMM_D, GMM_F = 8, 2048, 1792
 
 
 @pytest.fixture(scope="module")
@@ -96,6 +100,29 @@ def test_flash_grad_compiles(compile_tpu, B, S):
     _assert_kernel(compile_tpu(
         jax.grad(loss, argnums=(0, 1, 2)), ((B, S, KVH, G, D), F32),
         ((B, S, KVH, D), F32), ((B, S, KVH, D), F32)))
+
+
+@pytest.mark.parametrize("M", [128 * 4, 4096 * 4])
+@pytest.mark.parametrize("d,f", [(GMM_D, GMM_F), (GMM_F, GMM_D)])
+def test_gmm_forward_compiles(compile_tpu, M, d, f):
+    """The experts' grouped matmul (XLA's ragged dot) is a TPU kernel."""
+    from repro.models.moe import moe_gmm
+    hlo = compile_tpu(moe_gmm, ((M, d), F32), ((GMM_G, d, f), F32),
+                      ((GMM_G,), jnp.int32))
+    _assert_kernel(hlo)
+    assert "ragged-dot" in hlo
+
+
+@pytest.mark.parametrize("M", [128 * 4, 4096 * 4])
+def test_gmm_grad_compiles(compile_tpu, M):
+    from repro.models.moe import moe_gmm
+
+    def loss(x, w, gs):
+        return jnp.sum(moe_gmm(x, w, gs) ** 2)
+
+    _assert_kernel(compile_tpu(
+        jax.grad(loss, argnums=(0, 1)), ((M, GMM_D), F32),
+        ((GMM_G, GMM_D, GMM_F), F32), ((GMM_G,), jnp.int32)))
 
 
 def test_vtrace_compiles(compile_tpu):
