@@ -9,13 +9,28 @@ live in one VMEM scratch of that shape; the partition function reduces
 to one scalar and only the n chosen logits are exponentiated for
 weights, so no capacity-sized softmax is ever materialized.
 
-The top-n draw is n rounds over the scores, each one blocked pass of
-(BR, 128) rows that masks the previous round's pick and keeps, per tile
-position, the running best score, its flat index and its priority.
-The round's pick is the lowest flat index holding the maximum, which
-is `jax.lax.top_k`'s tie-break, so kernel and ref draw the same slots.
-Every value stays in vector registers: no argmax, no scatter, no
-dynamic slice of an in-register array (Mosaic lowers none of them).
+The top-n draw is a two-level tournament, so that its passes over C do
+not grow with n. The pass that writes the scores also keeps each
+block's best: the scores fall into blocks of TB whole rows (TB x 128
+slots), and for each block its maximum score and the lowest flat index
+that holds it go into two small lane-dense (SR, 128) arrays, one entry
+a block (1,024 blocks of 8 rows are one vreg each at C = 2^20). The
+winner is the maximum m of the block bests and the lowest flat index j
+among the entries equal to m: the lowest index of the global maximum,
+which is `jax.lax.top_k`'s tie-break, so kernel and ref draw the same
+slots in the same order, ties included. Each of the n rounds then
+  1. records the winner j, m and the priority of slot j;
+  2. loads block j // (TB * 128) alone, writes _NEG into slot j,
+     re-scans the block for its new best and puts that back into the
+     small arrays with an iota `where`;
+  3. takes the next winner from that new best and the other blocks'
+     best (reduced beside the re-scan), the lower index on a tie.
+A round thus touches one block and the small arrays, not all of C.
+The one dynamic access is the block's load and store: j is reduced to a
+scalar and gives an aligned row start into the scratch. Everything else
+is compare-and-select against iotas, so there is no scatter, no argmax
+and no dynamic slice of an in-register array (Mosaic lowers none of
+them).
 
 With fewer filled slots than n (avoid it — the draw is no longer
 without-replacement), surplus positions repeat the top draw exactly as
@@ -31,13 +46,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import interpret_mode
 
 _NEG = -3.4e38  # -inf stand-in: avoids inf-inf NaNs on the VPU
+_IMAX = jnp.iinfo(jnp.int32).max
 LANES = 128
-BR = 64  # rows per block of the draw pass: 8 vregs per carried tile
+BR = 64  # rows per chunk of the fill and partition passes: 8 vregs
+TB = 8   # rows per block of the draw's tournament: one vreg
 
 
-def _blocks(R):
-    """Rows per block and number of blocks for an (R, 128) tile."""
-    br = min(BR, R)
+def _blocks(R, br=BR):
+    """Rows per block and number of blocks for an (R, 128) tile. `layout`
+    makes R a multiple of 8, and of BR past BR, so each block is whole."""
+    br = min(br, R)
     return br, R // br
 
 
@@ -48,56 +66,89 @@ def _flat(b, br):
     return (b * br + row) * LANES + lane
 
 
-def _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, *, alpha, eps):
-    """s_ref <- masked α log(p + ε) + g (the ref's `scores`)."""
-    br, nb = _blocks(prio_ref.shape[0])
+def _best(s, idx):
+    """(maximum of s, lowest index of idx where s holds it), (1,1) each:
+    a block's best from its scores and flat indices, or the winner of
+    the small arrays from the block bests."""
+    m = jnp.max(s, axis=(0, 1), keepdims=True)
+    return m, jnp.min(jnp.where(s == m, idx, _IMAX), axis=(0, 1),
+                      keepdims=True)
 
-    def block(b, carry):
-        rows = pl.ds(pl.multiple_of(b * br, br), br)
-        valid = _flat(b, br) < nvalid
+
+def _at(sr, b):
+    """Mask of block b's entry in the (sr, 128) small arrays (one entry a
+    block, row-major)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (sr, LANES), 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (sr, LANES), 1)) == b
+
+
+def _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, *, alpha, eps):
+    """s_ref <- masked α log(p + ε) + g (the ref's `scores`), in chunks
+    of BR rows. -> the tournament's block bests (score, flat index),
+    (SR, 128) each; the entries past the last block hold -inf and never
+    win. A chunk's blocks are reduced side by side, not one per step."""
+    R = prio_ref.shape[0]
+    br, nc = _blocks(R)
+    tb, nb = _blocks(R, TB)
+    sr = -(-nb // LANES)
+
+    def chunk(c, bests):
+        rows = pl.ds(pl.multiple_of(c * br, br), br)
+        flat = _flat(c, br)
+        valid = flat < nvalid
         logits = jnp.where(valid, alpha * jnp.log(prio_ref[rows, :] + eps),
                            _NEG)
-        s_ref[rows, :] = jnp.where(valid, logits + gumbel_ref[rows, :], _NEG)
-        return carry
+        s = jnp.where(valid, logits + gumbel_ref[rows, :], _NEG)
+        s_ref[rows, :] = s
+        best, arg = bests
+        for k in range(br // tb):
+            m, j = _best(s[k * tb:(k + 1) * tb], flat[k * tb:(k + 1) * tb])
+            at = _at(sr, c * (br // tb) + k)
+            best, arg = jnp.where(at, m, best), jnp.where(at, j, arg)
+        return best, arg
 
-    jax.lax.fori_loop(0, nb, block, 0)
+    init = (jnp.full((sr, LANES), -jnp.inf, jnp.float32),
+            jnp.full((sr, LANES), _IMAX, jnp.int32))
+    return jax.lax.fori_loop(0, nc, chunk, init)
 
 
-def _draw(prio_ref, s_ref, n):
-    """n rounds of max+mask over s_ref. -> (idx, best score, priority of
-    the pick), each (1, n)."""
-    br, nb = _blocks(prio_ref.shape[0])
+def _draw(prio_ref, s_ref, bests, n):
+    """n tournament rounds over s_ref and its block bests. -> (idx, best
+    score, priority of the pick), each (1, n).
+
+    The carry holds the current winner (m, j). A round re-scans j's
+    block and, beside it, reduces the other blocks' bests; the next
+    winner is the better of the two, the lower index on a tie. So each
+    round waits on two reductions, not four."""
+    tb, _ = _blocks(prio_ref.shape[0], TB)
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
     def round_(i, carry):
-        prev, idxs, maxes, prios = carry
-
-        def block(b, best):
-            top, top_idx, top_p = best
-            rows = pl.ds(pl.multiple_of(b * br, br), br)
-            flat = _flat(b, br)
-            s = jnp.where(flat == prev, _NEG, s_ref[rows, :])
-            s_ref[rows, :] = s
-            up = s > top                       # strict: lower index wins
-            return (jnp.where(up, s, top), jnp.where(up, flat, top_idx),
-                    jnp.where(up, prio_ref[rows, :], top_p))
-
-        init = (jnp.full((br, LANES), -jnp.inf, jnp.float32),
-                jnp.zeros((br, LANES), jnp.int32),
-                jnp.zeros((br, LANES), jnp.float32))
-        top, top_idx, top_p = jax.lax.fori_loop(0, nb, block, init)
-        m = jnp.max(top, axis=(0, 1), keepdims=True)              # (1,1)
-        j = jnp.min(jnp.where(top == m, top_idx, jnp.iinfo(jnp.int32).max),
-                    axis=(0, 1), keepdims=True)
-        pj = jnp.sum(jnp.where(top_idx == j, top_p, 0.0), axis=(0, 1),
+        (best, arg), (m, j), idxs, maxes, prios = carry
+        b = j[0, 0] // (tb * LANES)
+        rows = pl.ds(pl.multiple_of(b * tb, tb), tb)
+        flat = _flat(b, tb)
+        hit = flat == j
+        pj = jnp.sum(jnp.where(hit, prio_ref[rows, :], 0.0), axis=(0, 1),
                      keepdims=True)
+        s = jnp.where(hit, _NEG, s_ref[rows, :])
+        s_ref[rows, :] = s
+        mb, jb = _best(s, flat)
+        at = _at(best.shape[0], b)
+        # with one block, every entry of the rest is -inf and jr is
+        # stale; mb >= _NEG then wins alone
+        mr, jr = _best(jnp.where(at, -jnp.inf, best), arg)
+        top = jnp.maximum(mb, mr)
+        nxt = jnp.minimum(jnp.where(mb == top, jb, _IMAX),
+                          jnp.where(mr == top, jr, _IMAX))
         here = pos == i
-        return (j, jnp.where(here, j, idxs), jnp.where(here, m, maxes),
-                jnp.where(here, pj, prios))
+        return ((jnp.where(at, mb, best), jnp.where(at, jb, arg)),
+                (top, nxt), jnp.where(here, j, idxs),
+                jnp.where(here, m, maxes), jnp.where(here, pj, prios))
 
-    init = (jnp.full((1, 1), -1, jnp.int32), jnp.zeros((1, n), jnp.int32),
+    init = (bests, _best(*bests), jnp.zeros((1, n), jnp.int32),
             jnp.zeros((1, n), jnp.float32), jnp.zeros((1, n), jnp.float32))
-    _, idxs, maxes, prios = jax.lax.fori_loop(0, n, round_, init)
+    _, _, idxs, maxes, prios = jax.lax.fori_loop(0, n, round_, init)
     return idxs, maxes, prios
 
 
@@ -132,8 +183,9 @@ def _log_partition(prio_ref, nvalid, *, alpha, eps):
 def _kernel(prio_ref, gumbel_ref, size_ref, idx_ref, w_ref, s_ref,
             *, n, alpha, beta, eps):
     nvalid = jnp.maximum(size_ref[...], 1)                        # (1,1)
-    _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, alpha=alpha, eps=eps)
-    idxs, _, prios = _draw(prio_ref, s_ref, n)
+    bests = _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, alpha=alpha,
+                         eps=eps)
+    idxs, _, prios = _draw(prio_ref, s_ref, bests, n)
     chosen = alpha * jnp.log(prios + eps)
     # n > size fallback: the first `size` positions hold every filled
     # slot (their scores dominate _NEG); surplus positions repeat the
@@ -159,8 +211,9 @@ def _topk_kernel(prio_ref, gumbel_ref, nvalid_ref, idx_ref, s_out_ref,
     guard stays with the caller, so an empty shard yields only _NEG
     candidates."""
     nvalid = nvalid_ref[...]                                      # (1,1)
-    _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, alpha=alpha, eps=eps)
-    idxs, vals, _ = _draw(prio_ref, s_ref, k)
+    bests = _fill_scores(prio_ref, gumbel_ref, s_ref, nvalid, alpha=alpha,
+                         eps=eps)
+    idxs, vals, _ = _draw(prio_ref, s_ref, bests, k)
     # surplus positions (k > nvalid): the draw loop picks _NEG slots
     # once the filled ones are spent, but top_k over the flat vector
     # walks the remaining -inf slots in index order — indices nvalid,

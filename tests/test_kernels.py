@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_hsd
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.models.moe import moe_gmm
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.replay_sample.kernel import TB
 from repro.kernels.replay_sample.ops import prioritized_sample
 from repro.kernels.replay_sample.ref import (prioritized_sample_ref,
                                              prioritized_weights_ref,
@@ -440,18 +441,53 @@ def test_advantages_ref_pins_legacy_inline_scans(rng):
 
 
 # --------------------------------------------------------- replay_sample
-@pytest.mark.parametrize("C,size,n", [
-    (512, 300, 64),
-    (2048, 2048, 128),                 # full buffer
-    (256, 17, 16),                     # nearly-empty, n == size-1 range
-    (131, 100, 1),                     # odd capacity, single draw
-    (64, 10, 32),                      # degenerate n > size fallback
-    (16384, 12000, 16),                # several row blocks of the draw
+_BLK = TB * 128      # slots in one block of the draw's tournament
+
+
+def _shape_scores(kind, prio, gumbel):
+    """Lay the top of the scores out over the tournament's blocks. Ties
+    are exact: equal priority and equal Gumbel value."""
+    if kind == "ties_across_blocks":        # lowest index first, and
+        hot = jnp.array([3 * _BLK, 3 * _BLK - 1, 5 * _BLK + 7, _BLK,
+                         _BLK - 1])         # a block's last before the
+        return prio.at[hot].set(2.0), gumbel.at[hot].set(30.0)  # next's
+    if kind == "ties_in_block":
+        hot = 2 * _BLK + jnp.array([1000, 5, 300, 6, 1023])
+        return prio.at[hot].set(2.0), gumbel.at[hot].set(30.0)
+    if kind == "many_in_block":             # 41 picks re-scan block 3
+        return prio, gumbel.at[3 * _BLK + jnp.arange(0, _BLK, 25)].add(12.0)
+    if kind == "all_in_block":              # every pick from block 5
+        return prio, gumbel.at[5 * _BLK:6 * _BLK].add(15.0)
+    if kind == "surplus":   # the order kept, every score far from 0: a
+        # surplus draw compares every filled slot's score, and near 0
+        # the relative check would read the log's rounding, not the draw
+        return _shape_scores("ties_across_blocks", prio, gumbel + 40.0)
+    return prio, gumbel
+
+
+_DRAW_PATTERNS = ("ties_across_blocks", "ties_in_block", "many_in_block",
+                  "all_in_block")
+
+
+@pytest.mark.parametrize("C,size,n,kind", [
+    pytest.param(512, 300, 64, None, id="512-300-64"),
+    pytest.param(2048, 2048, 128, None, id="2048-2048-128"),  # full buffer
+    pytest.param(256, 17, 16, None, id="256-17-16"),  # nearly-empty
+    pytest.param(131, 100, 1, None, id="131-100-1"),  # odd C, one draw
+    pytest.param(64, 10, 32, None, id="64-10-32"),    # n > size fallback
+    pytest.param(16384, 12000, 16, None,
+                 id="16384-12000-16"),        # several row blocks
+    # 16 tournament blocks: ties, and picks crowded into one block
+    *(pytest.param(16 * _BLK, 12 * _BLK, 128, kind, id=kind)
+      for kind in _DRAW_PATTERNS),
+    pytest.param(16 * _BLK, _BLK + 6, _BLK + 16, "surplus",
+                 id="surplus"),               # n > size over two blocks
 ])
-def test_replay_sample_kernel_matches_ref(C, size, n, rng):
+def test_replay_sample_kernel_matches_ref(C, size, n, kind, rng):
     ks = jax.random.split(rng, 2)
     prio = jnp.abs(jax.random.normal(ks[0], (C,))) + 0.01
     gumbel = jax.random.gumbel(ks[1], (C,))
+    prio, gumbel = _shape_scores(kind, prio, gumbel)
     i1, w1 = prioritized_sample_ref(prio, size, gumbel, n)
     i2, w2 = prioritized_sample(prio, jnp.int32(size), gumbel, n)
     assert np.array_equal(np.asarray(i1), np.asarray(i2))
@@ -509,14 +545,20 @@ def test_shard_topk_merge_matches_flat_sample(size, rng):
     assert np.array_equal(np.asarray(fw), np.asarray(w))
 
 
-@pytest.mark.parametrize("C,nvalid,k", [
-    (64, 40, 16),                      # one padded row block
-    (300, 300, 32),                    # full shard, odd capacity
-    (16384, 9000, 8),                  # several row blocks of the draw
-    (64, 5, 16),                       # surplus: k > nvalid
-    (64, 0, 4),                        # empty shard: only -inf
+@pytest.mark.parametrize("C,nvalid,k,kind", [
+    pytest.param(64, 40, 16, None, id="64-40-16"),  # one padded row block
+    pytest.param(300, 300, 32, None, id="300-300-32"),  # full, odd C
+    pytest.param(16384, 9000, 8, None,
+                 id="16384-9000-8"),          # several row blocks
+    pytest.param(64, 5, 16, None, id="64-5-16"),  # surplus: k > nvalid
+    pytest.param(64, 0, 4, None, id="64-0-4"),    # empty shard: only -inf
+    *(pytest.param(16 * _BLK, 12 * _BLK, 64, kind, id=kind)
+      for kind in _DRAW_PATTERNS),
+    pytest.param(16 * _BLK, _BLK + 6, _BLK + 16, "surplus",
+                 id="surplus"),               # k > nvalid over two blocks
+    pytest.param(16 * _BLK, 0, 16, "all_in_block", id="empty"),
 ])
-def test_shard_topk_kernel_matches_ref(C, nvalid, k, rng):
+def test_shard_topk_kernel_matches_ref(C, nvalid, k, kind, rng):
     """The per-shard Pallas candidate draw (interpret mode) picks the
     ref's indices bitwise, ties included, with the ref's scores to f32
     rounding (the kernel evaluates log on (R, 128) tiles)."""
@@ -526,6 +568,7 @@ def test_shard_topk_kernel_matches_ref(C, nvalid, k, rng):
     prio = prio.at[1::7].set(prio[0])          # priority ties
     gumbel = jax.random.gumbel(ks[1], (C,))
     gumbel = gumbel.at[1::7].set(gumbel[0])    # -> exact score ties
+    prio, gumbel = _shape_scores(kind, prio, gumbel)
     s1, i1 = shard_gumbel_topk_ref(prio, nvalid, gumbel, k)
     s2, i2 = shard_topk(prio, jnp.int32(nvalid), gumbel, k)
     assert np.array_equal(np.asarray(i1), np.asarray(i2))
