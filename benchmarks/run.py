@@ -13,14 +13,12 @@ from benchmarks.common import write_bench_json
 
 def main() -> None:
     from benchmarks import (table1_computing, fig3_topologies,
-                            fig5_simulation, fig6_sync, fused_superstep,
-                            hotpath, sec7_evolution, table2_features,
+                            fig5_simulation, fig6_sync, hotpath, sec7_evolution, table2_features,
                             roofline)
     mods = [("table1_computing", table1_computing),
             ("fig3_topologies", fig3_topologies),
             ("fig5_simulation", fig5_simulation),
             ("fig6_sync", fig6_sync),
-            ("fused_superstep", fused_superstep),
             ("hotpath", hotpath),
             ("sec7_evolution", sec7_evolution),
             ("table2_features", table2_features),

@@ -56,8 +56,9 @@ print("impala:", hist[-1], "plan:", plan.describe(),
 # learner consumer joined by a device-resident trajectory queue
 # (repro.core.pipeline). The queue depth is whatever staleness the
 # plan's sync discipline admits: this ssp plan allows the producer to
-# run 1 iteration ahead of the learner; a bsp plan would pin depth 0
-# (lockstep — bitwise identical to the fused run above).
+# run 1 iteration ahead of the learner, so the learner consumes a
+# trajectory one iteration old; a bsp plan would pin depth 0 (no queue:
+# the lockstep run above).
 pplan = DistPlan.flat(1, collective="allreduce", sync="ssp",
                       staleness_bound=1, max_delay=1)
 pcfg = TrainerConfig(algo="impala", iters=40, superstep=10, n_envs=16,

@@ -145,8 +145,8 @@ class AxisSpec:
         the Trainer's ``pipeline=`` mode (repro.core.sync.pipeline_depth):
         bsp -> 0 (lockstep), ssp -> staleness_bound, asp -> max_delay.
         Numerically the same staleness budget as `ring_extra` — the
-        fused path spends it as sampled policy lag, the pipelined path
-        as producer run-ahead."""
+        lockstep path spends it as sampled policy lag, the pipelined
+        path as producer run-ahead."""
         return _sync_pipeline_depth(SyncConfig(
             self.sync, self.size, self.max_delay, self.staleness_bound))
 
@@ -341,10 +341,9 @@ class DistPlan:
     def pipeline_depth(self) -> int:
         """Trajectory-queue depth of the plan in the Trainer's
         ``pipeline=`` mode: per-axis staleness budgets add, exactly as
-        the per-axis delay schedules add in the fused rendering. A pure
-        bsp plan has depth 0 — the pipelined superstep degenerates to
-        lockstep and stays bitwise the fused path (pinned in
-        tests/test_pipeline.py)."""
+        the per-axis delay schedules add in the lockstep rendering. A
+        pure bsp plan has depth 0: no queue, and the superstep is the
+        lockstep one."""
         return sum(a.pipeline_depth for a in self.axes)
 
     @property
@@ -523,9 +522,7 @@ class DistPlan:
         return total
 
     def actor_schedule(self, superstep_idx: int, default: int) -> int:
-        """Total env-shard count for superstep window `superstep_idx`
-        (iteration // cfg.superstep — NOT the dispatch count, so fused
-        and unfused fits reshard at the same iteration boundaries; the
+        """Total env-shard count for superstep `superstep_idx` (the
         schedule cycles); `default` when the plan is not elastic."""
         if self.actors is None:
             return default

@@ -23,20 +23,19 @@ Design points:
     no-op returning ``ok=False`` (backpressure: the element is
     *refused*, never silently dropped or overwritten);
     ``queue_pop`` on an empty queue is a guarded no-op returning the
-    (stale) head-slot contents and ``ok=False``. The overlap driver's
+    (stale) head-slot contents and ``ok=False``. The Trainer's
     static schedule never trips either guard — steady state holds
     exactly ``depth`` items — but the ops stay safe under jit/scan
     where Python-level control flow is unavailable.
 
   * **Staleness-bounded.** Capacity is the pipeline depth the
     DistPlan's per-axis sync discipline admits
-    (``DistPlan.pipeline_depth``): bsp admits none (depth 0 renders as
-    lockstep — push-then-pop through one slot, bitwise the fused
-    path), ssp admits ``staleness_bound``, asp ``max_delay``. A
-    producer can therefore never run further ahead than the sync
-    discipline already allowed as policy lag — the queue *realizes*
-    structurally the staleness the fused path only models with delay
-    schedules.
+    (``DistPlan.pipeline_depth``): bsp admits none (depth 0 is
+    lockstep, with no queue), ssp admits ``staleness_bound``, asp
+    ``max_delay``. A producer can therefore never run further ahead
+    than the sync discipline already allowed as policy lag — the queue
+    *realizes* structurally the staleness the lockstep path only
+    models with delay schedules.
 
 Counters are monotonically increasing int32 (slot = counter %
 capacity), so ``size = tail - head`` needs no emptiness flag and

@@ -39,9 +39,9 @@ def pipeline_depth(cfg: SyncConfig) -> int:
     depth of the Trainer's ``pipeline=`` mode (repro.core.pipeline).
 
     The mapping is the same staleness budget `make_delays` spends as
-    random policy-lag: BSP admits none (depth 0 = lockstep, bitwise the
-    fused path), SSP admits its bound, ASP its worst case. The fused
-    path *models* that staleness by reading lagged params out of the
+    random policy-lag: BSP admits none (depth 0 = lockstep, no queue),
+    SSP admits its bound, ASP its worst case. The lockstep path
+    *models* that staleness by reading lagged params out of the
     actor ring; the pipelined path *realizes* it — a trajectory
     consumed at iteration t was produced `depth` iterations earlier
     with the params then newest, so the actor-param lag is structural

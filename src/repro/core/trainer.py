@@ -37,34 +37,27 @@ collective (§3) and sync discipline (§6):
     data plan; a size-1 replay axis is left unwrapped (a data axis by
     construction).
 
-`fit(fused=True)` scans `superstep` iterations (rollout -> learner_step
--> lag-ring rotate) inside ONE jitted `lax.scan`: the Python loop
-dispatches iters/K programs and reads metrics back once per superstep
-instead of blocking on `float(...)` every iteration.  `fit(fused=False)`
-runs the identical iteration body one step at a time — numerically
-equivalent (tests/test_trainer.py) but host-bound; the speedup is
-measured in benchmarks/fused_superstep.py.
+`fit` runs `superstep` iterations (rollout -> learner_step -> lag-ring
+rotate) under ONE jitted `lax.scan` (`_superstep`): the host dispatches
+iters/K programs and reads the metrics back once per dispatch. Where
+the dispatches fall does not change the numerics: K dispatches of one
+iteration equal one dispatch of K, bitwise (tests/test_trainer.py).
 
 **Pipelined mode** (`TrainerConfig.pipeline=True`, the survey §2
 actor/learner decoupling — Gorila/Ape-X, SRL's description/execution
-split): the superstep body is split at the trajectory seam into a
-rollout *producer* and a learner *consumer* joined by a fixed-capacity
-device-resident trajectory queue (repro.core.pipeline) riding in the
-carry. The queue depth is what the plan's per-axis sync discipline
-admits (`DistPlan.pipeline_depth`): bsp -> 0, ssp -> staleness_bound,
-asp -> max_delay. At depth 0 the tick degenerates to push-then-pop
-through one slot — lockstep, f32-bitwise the fused path (pinned in
-tests/test_pipeline.py). At depth >= 1 the producer runs `depth`
-iterations AHEAD: tick t pops the trajectory produced at tick t-depth
-(no data dependency on this tick's rollout) and produces the
-trajectory for iteration t+depth, so XLA's scheduler is free to
-execute simulation of iteration t+depth concurrently with the learner
-update of iteration t — the staleness the fused path only *models* as
-sampled policy-lag delays becomes real overlapped compute, with the
-actor-param ring supplying the lagged policy. Ticks are unrolled (not
-scanned): scan bodies execute serially, which would hide the
-producer/consumer independence from the scheduler. Walltime overlap is
-measured in benchmarks/pipeline_overlap.py -> BENCH_pipeline.json.
+split): the iteration is split at the trajectory seam into a rollout
+*producer* and a learner *consumer* joined by a fixed-capacity
+device-resident trajectory queue (repro.core.pipeline), held in the
+simulation carry as `sim["queue"]`. The queue depth d is what the
+plan's per-axis sync discipline admits (`DistPlan.pipeline_depth`):
+bsp -> 0, ssp -> staleness_bound, asp -> max_delay. At depth 0 there is
+no queue and the superstep is the one above. At depth d >= 1 each tick
+pops the trajectory produced d ticks earlier, produces iteration it+d
+with the newest params and pushes it: the learner consumes a
+trajectory d iterations old, so the staleness the lockstep path models
+as sampled policy lag is structural. A prologue fills the queue with
+iterations 0..d-1; the queue then persists across dispatches, so a
+chunked fit equals a one-shot fit bitwise (tests/test_pipeline.py).
 """
 from __future__ import annotations
 
@@ -138,7 +131,7 @@ class Trainer:
                 f"actors= schedule {plan.actors}: the trajectory queue's "
                 f"buffer shape is fixed per compile, so in-flight "
                 f"trajectories cannot be resharded — use a constant "
-                f"schedule or fused mode")
+                f"schedule or pipeline=False")
         self.env = env
         self.cfg = cfg
         self.plan = plan
@@ -169,7 +162,7 @@ class Trainer:
                 f"{shard.name!r}: the trajectory queue's item template "
                 f"is shape-traced outside the mesh program, where the "
                 f"gather-per-use actor params have no axis environment "
-                f"— use role 'shard' (ZeRO-2) or fused mode")
+                f"— use role 'shard' (ZeRO-2) or pipeline=False")
         if self._sharded and not hasattr(self.agent, "opt"):
             raise ValueError(
                 f"algorithm {cfg.algo!r} exposes no `.opt` optimizer — "
@@ -191,7 +184,7 @@ class Trainer:
                 f"axis {rax.name!r}: the decoupled superstep reorders "
                 f"the add_batch/sample interleaving against the "
                 f"sharded buffer and that combination has no validated "
-                f"parity — use the fused superstep (pipeline=False) or "
+                f"parity — use pipeline=False or "
                 f"drop the replay axis")
         self._replay_service = None
         self.partition_replay = None
@@ -249,16 +242,16 @@ class Trainer:
         self._step_cache = {}
         self.actor_shards = []   # actual env count per superstep dispatch
         # trajectory-queue depth the plan's sync hierarchy admits for
-        # the decoupled actor-learner pipeline; 0 (lockstep) unless
-        # cfg.pipeline asks for the split superstep
+        # the decoupled actor-learner pipeline; 0 (lockstep, no queue)
+        # unless cfg.pipeline asks for it
         self.pipeline_depth = plan.pipeline_depth if cfg.pipeline else 0
 
     @property
     def pipeline_capacity(self) -> Optional[int]:
-        """Ring capacity of the trajectory queue (None when fused):
-        steady state holds exactly `pipeline_depth` in-flight
-        trajectories; depth 0 still needs the one lockstep slot."""
-        return max(self.pipeline_depth, 1) if self.cfg.pipeline else None
+        """Slots of the trajectory queue in `sim`, which holds exactly
+        `pipeline_depth` in-flight trajectories (None at depth 0: no
+        queue)."""
+        return self.pipeline_depth or None
 
     # ---- episode accounting (carried across iterations) --------------
     @staticmethod
@@ -286,12 +279,12 @@ class Trainer:
         ep_ret = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1), ep_last)
         return ep_run, ep_ret
 
-    # ---- producer/consumer halves (shared by fused + pipelined) ------
+    # ---- producer/consumer halves of a tick ---------------------------
     def _iter_key(self, it):
         """(k_roll, k_learn) for iteration `it` — one deterministic
-        stream per iteration, independent of which program (fused tick,
-        producer, consumer) derives it, so the pipelined split consumes
-        randomness bitwise-identically to the fused scan."""
+        stream per iteration, whichever tick or program derives it, so
+        a pipelined fit draws the same randomness for an iteration as
+        a lockstep one."""
         key = jax.random.fold_in(self._base_key, it)
         if self.mesh is not None:
             # per-device RNG stream keyed by the FLAT device index of
@@ -303,15 +296,11 @@ class Trainer:
             key = jax.random.fold_in(key, self.plan.sim_index())
         return jax.random.split(key)
 
-    def _produce(self, state, env_state, it, delay=None):
-        """Rollout-producer half: one trajectory for iteration `it`
-        plus its bootstrap observation (the queue item — boot_obs must
-        ride along because the consumer never sees the env state).
-        `delay` defaults to the deterministic policy-lag floor: in
-        pipelined mode the producer always acts with the newest params
-        available, and any extra staleness is structural (the queue
-        depth), not sampled."""
-        delay = self.cfg.policy_lag if delay is None else delay
+    def _produce(self, state, env_state, it, delay):
+        """Rollout-producer half: one trajectory for iteration `it`,
+        acting with the params `delay` steps old, plus its bootstrap
+        observation (the queue item — boot_obs must ride along because
+        the consumer never sees the env state)."""
         with jax.named_scope("rollout"):
             k_roll, _ = self._iter_key(it)
             actor = self.agent.actor_policy(state, delay)
@@ -337,302 +326,116 @@ class Trainer:
                            for k, v in metrics.items()}
         return state, ep_run, ep_ret, metrics
 
-    # ---- one training iteration (shared by fused/unfused paths) ------
-    def _iteration(self, carry, xs):
+    # ---- one tick: a training iteration, pipelined at depth >= 1 ----
+    def _tick(self, carry, xs):
+        """One scanned tick for consumer iteration `it`. At depth 0 it
+        produces and consumes iteration `it`. At depth d >= 1 it pops
+        the item produced d ticks earlier, produces iteration `it + d`,
+        pushes it, and consumes the popped item."""
         state, sim = carry
         it, delay = xs
-        item, env_state = self._produce(state, sim["env"], it, delay)
-        state, ep_run, ep_ret, metrics = self._consume(
-            state, sim["ep_run"], sim["ep_last"], item, it)
-        sim = {"env": env_state, "ep_run": ep_run, "ep_last": ep_ret}
-        return (state, sim), metrics
-
-    # ---- superstep: k fused iterations in one program ----------------
-    def _superstep(self, k: int, donate: bool = None):
-        """Jitted k-iteration program. With `donate` (cfg.donate by
-        default) the `state`/`sim` argument buffers are donated to
-        their same-shaped outputs, so the carried pytrees — DQN's
-        capacity×transition replay store, the actor-param ring, env
-        state — update in place instead of being copied once per
-        dispatch (zero-copy superstep; measured in
-        benchmarks/hotpath.py)."""
-        donate = self.cfg.donate if donate is None else donate
-        cache_key = (k, donate)
-        if cache_key in self._step_cache:
-            return self._step_cache[cache_key]
-        donate_argnums = (0, 1) if donate else ()
-
-        def body(state, sim, its, delays):
-            (state, sim), metrics = jax.lax.scan(
-                self._iteration, (state, sim), (its, delays))
-            return state, sim, metrics
-
-        if self.mesh is None:
-            fn = jax.jit(body, donate_argnums=donate_argnums)
-        else:
-            from jax.experimental.shard_map import shard_map
-            nd = len(self.plan.axes)
-
-            def worker(state, sim, its, delays):
-                # shard_map keeps one length-1 dim per mesh axis on the
-                # sharded leaves — strip before the body, restore after
-                state, sim, metrics = body(
-                    strip_worker_dim(state, nd),
-                    strip_worker_dim(sim, nd),
-                    its, delays.reshape(delays.shape[0]))
-                return (restore_worker_dim(state, nd),
-                        restore_worker_dim(sim, nd), metrics)
-
-            w = P(*self.plan.axis_names)
-            fn = jax.jit(shard_map(
-                worker, mesh=self.mesh,
-                in_specs=(w, w, P(), P(None, *self.plan.axis_names)),
-                out_specs=(w, w, P()), check_rep=False),
-                donate_argnums=donate_argnums)
-        self._step_cache[cache_key] = fn
-        return fn
-
-    # ---- pipelined superstep: decoupled producer/consumer ------------
-    def _pipe_tick(self, state, sim, queue, it, delay):
-        """One pipelined tick for consumer iteration `it`.
-
-        depth 0: lockstep — push-then-pop through a one-slot queue is
-        the identity on the item stream, so the round-trip is compiled
-        away (the queue rides the carry untouched). This is not just an
-        optimization: the buffer write would force XLA to materialize
-        `traj` instead of fusing it into the consumer's reductions,
-        drifting ~1 ulp from the fused program and breaking the depth-0
-        bitwise guarantee (tests/test_pipeline.py pins it).
-
-        depth d >= 1: pop FIRST (the popped item — produced d ticks ago
-        — depends only on the carry-in queue, never on this tick's
-        rollout), then produce iteration `it + d` and push. The two
-        halves share only the carry-in `state`, so XLA schedules the
-        rollout of iteration it+d concurrently with the learner update
-        of iteration it; the tick's critical path is
-        max(t_produce, t_consume) instead of their sum."""
         d = self.pipeline_depth
         if d == 0:
-            item_c, env_state = self._produce(state, sim["env"], it,
-                                              delay)
+            item, env_state = self._produce(state, sim["env"], it, delay)
         else:
-            queue, item_c, _ = queue_pop(queue)
+            queue, item, _ = queue_pop(sim["queue"])
             item_p, env_state = self._produce(state, sim["env"], it + d,
                                               delay)
             queue, _ = queue_push(queue, item_p)
+            sim = dict(sim, queue=queue)
         state, ep_run, ep_ret, metrics = self._consume(
-            state, sim["ep_run"], sim["ep_last"], item_c, it)
-        sim = {"env": env_state, "ep_run": ep_run, "ep_last": ep_ret}
-        return state, sim, queue, metrics
+            state, sim["ep_run"], sim["ep_last"], item, it)
+        sim = dict(sim, env=env_state, ep_run=ep_run, ep_last=ep_ret)
+        return (state, sim), metrics
 
-    def _pipeline_superstep(self, k: int, donate: bool = None):
-        """Jitted k-tick pipelined program (the consumer-side lowering;
-        the queue rides the carry and is donated with state/sim).
+    # ---- programs: k ticks in one dispatch, and the queue's fill -----
+    def _jit(self, body, sharded_out, donate_argnums):
+        """Jit `body(state, sim, its, delays)` for the plan. Under a mesh
+        the body runs per device in one `shard_map`: `state`, `sim` and
+        the results flagged in `sharded_out` carry one leading dim per
+        mesh axis, the others are replicated."""
+        if self.mesh is None:
+            return jax.jit(body, donate_argnums=donate_argnums)
+        from jax.experimental.shard_map import shard_map
+        nd = len(self.plan.axes)
 
-        At depth >= 1 ticks are UNROLLED — a lax.scan body executes
-        serially under the XLA schedulers, which would hide the
-        producer/consumer independence `_pipe_tick` sets up. At depth 0
-        there is nothing to overlap (lockstep by definition), so ticks
-        run under lax.scan like the fused path: unrolling lets XLA fuse
-        across tick boundaries and drift ~1 ulp from the scanned
-        program, which would break the depth-0 bitwise guarantee."""
+        def worker(state, sim, its, delays):
+            # shard_map keeps one length-1 dim per mesh axis on the
+            # sharded leaves — strip before the body, restore after
+            out = body(strip_worker_dim(state, nd),
+                       strip_worker_dim(sim, nd),
+                       its, delays.reshape(delays.shape[0]))
+            return tuple(restore_worker_dim(o, nd) if sh else o
+                         for o, sh in zip(out, sharded_out))
+
+        w = P(*self.plan.axis_names)
+        return jax.jit(shard_map(
+            worker, mesh=self.mesh,
+            in_specs=(w, w, P(), P(None, *self.plan.axis_names)),
+            out_specs=tuple(w if sh else P() for sh in sharded_out),
+            check_rep=False), donate_argnums=donate_argnums)
+
+    def _superstep(self, k: int, donate: bool = None):
+        """Jitted k-tick program `(state, sim, its, delays) -> (state,
+        sim, metrics)`. With `donate` (cfg.donate by default) the
+        `state`/`sim` argument buffers are donated to their same-shaped
+        outputs, so the carried pytrees — DQN's capacity×transition
+        replay store, the actor-param ring, env state, the trajectory
+        queue — update in place instead of being copied once per
+        dispatch."""
         donate = self.cfg.donate if donate is None else donate
-        cache_key = ("pipe", k, donate)
-        if cache_key in self._step_cache:
-            return self._step_cache[cache_key]
-        donate_argnums = (0, 1, 2) if donate else ()
+        cache_key = (k, donate)
+        if cache_key not in self._step_cache:
+            def body(state, sim, its, delays):
+                (state, sim), metrics = jax.lax.scan(
+                    self._tick, (state, sim), (its, delays))
+                return state, sim, metrics
 
-        def body(state, sim, queue, its, delays):
-            if self.pipeline_depth == 0:
-                def tick(carry, xs):
-                    state, sim, queue = carry
-                    state, sim, queue, m = self._pipe_tick(
-                        state, sim, queue, *xs)
-                    return (state, sim, queue), m
-                (state, sim, queue), metrics = jax.lax.scan(
-                    tick, (state, sim, queue), (its, delays))
-                return state, sim, queue, metrics
-            per = []
-            for j in range(k):
-                state, sim, queue, m = self._pipe_tick(
-                    state, sim, queue, its[j], delays[j])
-                # fence the carry at tick boundaries: without it XLA
-                # fuses across ticks and a k-tick program drifts ~1 ulp
-                # from k dispatches of 1-tick programs (chunked fits
-                # stop being bitwise one-shot fits — the fence restores
-                # that for value-based learners; policy-gradient
-                # learners with internal epoch scans keep ~1-ulp chunk
-                # variance, pinned as allclose in tests). The fence adds
-                # no serialization the dataflow didn't already have —
-                # produce(t+1) reads consume(t)'s state — so the
-                # within-tick produce/consume independence survives.
-                state, sim, queue = jax.lax.optimization_barrier(
-                    (state, sim, queue))
-                per.append(m)
-            metrics = {key: jnp.stack([m[key] for m in per])
-                       for key in per[0]}
-            return state, sim, queue, metrics
+            self._step_cache[cache_key] = self._jit(
+                body, (True, True, False), (0, 1) if donate else ())
+        return self._step_cache[cache_key]
 
-        if self.mesh is None:
-            fn = jax.jit(body, donate_argnums=donate_argnums)
-        else:
-            from jax.experimental.shard_map import shard_map
-            nd = len(self.plan.axes)
+    def _fill(self):
+        """Jitted prologue `(state, sim, its, delays) -> (sim,)` of a
+        pipelined fit: produces iterations its[0..d-1] into the empty
+        queue. `state` is only read, so only `sim` is donated."""
+        d = self.pipeline_depth
 
-            def worker(state, sim, queue, its, delays):
-                state, sim, queue, metrics = body(
-                    strip_worker_dim(state, nd),
-                    strip_worker_dim(sim, nd),
-                    strip_worker_dim(queue, nd), its,
-                    delays.reshape(delays.shape[0]))
-                return (restore_worker_dim(state, nd),
-                        restore_worker_dim(sim, nd),
-                        restore_worker_dim(queue, nd), metrics)
-
-            w = P(*self.plan.axis_names)
-            fn = jax.jit(shard_map(
-                worker, mesh=self.mesh,
-                in_specs=(w, w, w, P(), P(None, *self.plan.axis_names)),
-                out_specs=(w, w, w, P()), check_rep=False),
-                donate_argnums=donate_argnums)
-        self._step_cache[cache_key] = fn
-        return fn
-
-    def _producer_program(self, k: int):
-        """Jitted k-iteration rollout-only program (the producer-side
-        lowering): fills the queue with trajectories for iterations
-        its[0..k-1] before the first pipelined tick runs. `state` is
-        read-only here — the first tick still needs its buffers, so only
-        sim/queue are donated."""
-        cache_key = ("fill", k)
-        if cache_key in self._step_cache:
-            return self._step_cache[cache_key]
-        donate_argnums = (1, 2) if self.cfg.donate else ()
-
-        def body(state, sim, queue, its, delays):
-            env_state = sim["env"]
-            for j in range(k):
-                item, env_state = self._produce(state, env_state,
-                                                its[j], delays[j])
+        def body(state, sim, its, delays):
+            env_state, queue = sim["env"], sim["queue"]
+            for j in range(d):
+                item, env_state = self._produce(state, env_state, its[j],
+                                                delays[j])
                 queue, _ = queue_push(queue, item)
-            sim = {"env": env_state, "ep_run": sim["ep_run"],
-                   "ep_last": sim["ep_last"]}
-            return sim, queue
+            return (dict(sim, env=env_state, queue=queue),)
 
-        if self.mesh is None:
-            fn = jax.jit(body, donate_argnums=donate_argnums)
-        else:
-            from jax.experimental.shard_map import shard_map
-            nd = len(self.plan.axes)
+        return self._jit(body, (True,), (1,) if self.cfg.donate else ())
 
-            def worker(state, sim, queue, its, delays):
-                sim, queue = body(
-                    strip_worker_dim(state, nd),
-                    strip_worker_dim(sim, nd),
-                    strip_worker_dim(queue, nd), its,
-                    delays.reshape(delays.shape[0]))
-                return (restore_worker_dim(sim, nd),
-                        restore_worker_dim(queue, nd))
+    def _init_queue(self, state):
+        """Empty trajectory queue of `pipeline_depth` slots, shaped for
+        one device. Item shapes come from a shape-only trace of the
+        rollout with a dummy key, because `_iter_key` folds in the
+        device's index, which exists only inside shard_map."""
+        per = self.cfg.n_envs // self.plan.sim_devices
 
-            w = P(*self.plan.axis_names)
-            fn = jax.jit(shard_map(
-                worker, mesh=self.mesh,
-                in_specs=(w, w, w, P(), P(None, *self.plan.axis_names)),
-                out_specs=(w, w), check_rep=False),
-                donate_argnums=donate_argnums)
-        self._step_cache[cache_key] = fn
-        return fn
-
-    def _consumer_program(self, k: int):
-        """Jitted k-iteration learner-only program (the consumer-side
-        lowering): pops one queued trajectory per iteration and runs
-        learner_step + episode accounting on it. `fit` never calls this
-        — the pipelined tick fuses both halves — but it is the serial
-        half of the decoupled baseline benchmarks/pipeline_overlap.py
-        measures the pipelined program against, and the natural drain
-        primitive for a future multi-host split (ROADMAP)."""
-        cache_key = ("drain", k)
-        if cache_key in self._step_cache:
-            return self._step_cache[cache_key]
-        donate_argnums = (0, 1, 2) if self.cfg.donate else ()
-
-        def body(state, sim, queue, its):
-            ep_run, ep_last = sim["ep_run"], sim["ep_last"]
-            per = []
-            for j in range(k):
-                queue, item, _ = queue_pop(queue)
-                state, ep_run, ep_ret, m = self._consume(
-                    state, ep_run, ep_last, item, its[j])
-                ep_last = ep_ret
-                per.append(m)
-            metrics = {key: jnp.stack([m[key] for m in per])
-                       for key in per[0]}
-            sim = {"env": sim["env"], "ep_run": ep_run,
-                   "ep_last": ep_last}
-            return state, sim, queue, metrics
-
-        if self.mesh is None:
-            fn = jax.jit(body, donate_argnums=donate_argnums)
-        else:
-            from jax.experimental.shard_map import shard_map
-            nd = len(self.plan.axes)
-
-            def worker(state, sim, queue, its):
-                state, sim, queue, metrics = body(
-                    strip_worker_dim(state, nd),
-                    strip_worker_dim(sim, nd),
-                    strip_worker_dim(queue, nd), its)
-                return (restore_worker_dim(state, nd),
-                        restore_worker_dim(sim, nd),
-                        restore_worker_dim(queue, nd), metrics)
-
-            w = P(*self.plan.axis_names)
-            fn = jax.jit(shard_map(
-                worker, mesh=self.mesh,
-                in_specs=(w, w, w, P()),
-                out_specs=(w, w, w, P()), check_rep=False),
-                donate_argnums=donate_argnums)
-        self._step_cache[cache_key] = fn
-        return fn
-
-    def _init_queue(self, state, sim):
-        """Empty trajectory queue sized for `pipeline_capacity` items.
-
-        Item shapes come from a shape-only trace (eval_shape) of the
-        producer on PER-DEVICE inputs — a dedicated closure with a dummy
-        key, because `_iter_key` folds in `plan.linear_index()`
-        (axis_index), which only exists inside shard_map. Under a mesh
-        the queue leaves get the same leading mesh dims as state/sim so
-        one `P(*axis_names)` spec shards every carry argument alike."""
-        cap = self.pipeline_capacity
-        nd = 0 if self.mesh is None else len(self.plan.axes)
-        sds = lambda t: jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape[nd:], a.dtype), t)
-
-        def one_item(state, env_state):
+        def one_item(state, key):
             actor = self.agent.actor_policy(state, self.cfg.policy_lag)
             traj, env_state = rollout(
-                self.agent.policy, actor, self.env,
-                jax.random.PRNGKey(0), env_state, self.cfg.unroll)
+                self.agent.policy, actor, self.env, key,
+                self.env.reset_batch(key, per), self.cfg.unroll)
             return {"traj": traj,
                     "boot": jax.vmap(self.env.obs)(env_state)}
 
-        item = jax.eval_shape(one_item, sds(state), sds(sim["env"]))
-        if self.mesh is None:
-            return queue_init(item, cap)
-        lead = self.plan.mesh_shape
-        buf = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(lead + (cap,) + tuple(s.shape), s.dtype),
-            item)
-        return {"buf": buf, "head": jnp.zeros(lead, jnp.int32),
-                "tail": jnp.zeros(lead, jnp.int32)}
+        item = jax.eval_shape(one_item, state, jax.random.PRNGKey(0))
+        return queue_init(item, self.pipeline_depth)
 
     # ---- state/schedule construction ---------------------------------
     def _shard_sim(self, sim):
         """Reshape a host-layout sim carry (flat env batch) into the
         plan's mesh layout: one leading dim per mesh axis, row-major, so
         device (i0, i1, ...) owns the same contiguous env slice its flat
-        linear index would."""
+        linear index would. `ep_last` and the (empty, one device's)
+        trajectory queue are replicated."""
         if self.mesh is None:
             return sim
         shape = self.plan.mesh_shape
@@ -644,9 +447,10 @@ class Trainer:
         lay = lambda a: jnp.broadcast_to(
             a.reshape(sshape + (per,) + a.shape[1:]),
             shape + (per,) + a.shape[1:])
-        return {"env": jax.tree_util.tree_map(lay, sim["env"]),
-                "ep_run": lay(sim["ep_run"]),
-                "ep_last": jnp.broadcast_to(sim["ep_last"], shape)}
+        rep = lambda a: jnp.broadcast_to(a, shape + a.shape)
+        return {name: jax.tree_util.tree_map(
+                    lay if name in ("env", "ep_run") else rep, tree)
+                for name, tree in sim.items()}
 
     def _init_all(self):
         cfg = self.cfg
@@ -687,6 +491,8 @@ class Trainer:
         sim = {"env": self.env.reset_batch(k_env, cfg.n_envs),
                "ep_run": jnp.zeros((cfg.n_envs,)),
                "ep_last": jnp.full((), jnp.nan)}
+        if self.pipeline_depth:
+            sim["queue"] = self._init_queue(state)
         delays = (self.plan.make_delay_schedule(cfg.iters, k_delay)
                   + cfg.policy_lag)
         if self.mesh is not None:
@@ -780,77 +586,49 @@ class Trainer:
                 lambda a: a[keep + (slice(0, per_new),)], sim["env"])
             ep_run = sim["ep_run"][keep + (slice(0, per_new),)]
         else:
-            fresh = {"env": self.env.reset_batch(
-                         key, (per_new - per_cur) * nd),
-                     "ep_run": jnp.zeros(((per_new - per_cur) * nd,)),
-                     "ep_last": sim["ep_last"]}
-            fresh = self._shard_sim(fresh)
+            fresh = self._shard_sim({
+                "env": self.env.reset_batch(key, (per_new - per_cur) * nd),
+                "ep_run": jnp.zeros(((per_new - per_cur) * nd,))})
             env = jax.tree_util.tree_map(
                 lambda a, b: jnp.concatenate([a, b], axis=lead),
                 sim["env"], fresh["env"])
             ep_run = jnp.concatenate([sim["ep_run"], fresh["ep_run"]],
                                      axis=lead)
-        return {"env": env, "ep_run": ep_run, "ep_last": sim["ep_last"]}
+        return dict(sim, env=env, ep_run=ep_run)
 
     def lower(self, k: int = None, donate: bool = None):
         """Lower (without running) one superstep — lets benchmarks
         inspect the collective schedule (HLO) per plan and the donation
-        plan (compile().memory_analysis())."""
+        plan (compile().memory_analysis()); at depth >= 1 the trajectory
+        queue rides in `sim`."""
         k = self.cfg.superstep if k is None else k
         state, sim, delays = self._init_all()
         its = jnp.arange(k, dtype=jnp.int32)
         return self._superstep(k, donate).lower(state, sim, its,
                                                 delays[:k])
 
-    def lower_pipelined(self, k: int = None, donate: bool = None):
-        """Lower (without running) one pipelined superstep — the
-        consumer-side program with the trajectory queue in its carry."""
-        k = self.cfg.superstep if k is None else k
-        state, sim, delays = self._init_all()
-        queue = self._init_queue(state, sim)
-        its = jnp.arange(k, dtype=jnp.int32)
-        return self._pipeline_superstep(k, donate).lower(
-            state, sim, queue, its,
-            jnp.full_like(delays[:k], self.cfg.policy_lag))
-
     # ---- the driver --------------------------------------------------
-    def fit(self, fused: bool = True):
-        """Train for cfg.iters iterations. Returns (TrainState, history);
-        with a multi-device plan the returned state is device 0's
-        replica."""
+    def fit(self):
+        """Train for cfg.iters iterations, one dispatch per cfg.superstep
+        iterations. Returns (TrainState, history); with a multi-device
+        plan the returned state is device 0's replica."""
         cfg = self.cfg
         state, sim, delays = self._init_all()
-        queue = None
-        if cfg.pipeline:
-            # the pipelined producer acts at the constant policy_lag
-            # floor — structural queue staleness replaces the sampled
-            # delay schedule — but the delay still enters the program
-            # as an INPUT so the ring read lowers to the same dynamic
-            # slice as the fused path (depth-0 bitwise guarantee)
+        d = self.pipeline_depth
+        if d:
+            # the producer acts with the newest params (the policy_lag
+            # floor): the staleness is the queue depth, not sampled
             delays = jnp.full_like(delays, cfg.policy_lag)
-            # prologue: fill the queue so the producer starts `depth`
-            # iterations ahead of the consumer. The queue then PERSISTS
-            # across superstep dispatches (no drain at chunk
-            # boundaries), so chunked fits equal one-shot fits. The
-            # producer over-runs by `depth` wasted rollouts at the tail
-            # — the price of a uniform tick program.
-            queue = self._init_queue(state, sim)
-            if self.pipeline_depth:
-                fill = self._producer_program(self.pipeline_depth)
-                its0 = jnp.arange(self.pipeline_depth, dtype=jnp.int32)
-                sim, queue = fill(state, sim, queue, its0,
-                                  delays[:self.pipeline_depth])
-        K = cfg.superstep if fused else 1
+            # prologue: produce iterations 0..d-1. The queue then
+            # persists across dispatches; the producer over-runs by d
+            # wasted rollouts at the tail.
+            (sim,) = self._fill()(state, sim,
+                                  jnp.arange(d, dtype=jnp.int32),
+                                  delays[:d])
         history = []
-        start = 0
         self.actor_shards = []
-        while start < cfg.iters:
-            k = min(K, cfg.iters - start)
-            # the actors= schedule is indexed by cfg.superstep-iteration
-            # window (not dispatch count), so fused and unfused runs
-            # reshard at the same iteration boundaries and stay
-            # numerically equivalent
-            s_idx = start // cfg.superstep
+        for s_idx, start in enumerate(range(0, cfg.iters, cfg.superstep)):
+            k = min(cfg.superstep, cfg.iters - start)
             n_envs = self.plan.actor_schedule(s_idx, cfg.n_envs)
             # reshard key offset far above any iteration index so elastic
             # env resets never alias an iteration's rollout stream
@@ -859,14 +637,8 @@ class Trainer:
                 jax.random.fold_in(self._base_key, (1 << 20) + s_idx))
             self.actor_shards.append(n_envs)
             its = jnp.arange(start, start + k, dtype=jnp.int32)
-            if cfg.pipeline:
-                step = self._pipeline_superstep(k)
-                state, sim, queue, metrics = step(
-                    state, sim, queue, its, delays[start:start + k])
-            else:
-                step = self._superstep(k)
-                state, sim, metrics = step(state, sim, its,
-                                           delays[start:start + k])
+            state, sim, metrics = self._superstep(k)(
+                state, sim, its, delays[start:start + k])
             metrics = jax.device_get(metrics)  # ONE host sync per chunk
             for j in range(k):
                 it = start + j
@@ -874,7 +646,6 @@ class Trainer:
                     history.append({"iter": it, **{
                         name: round(float(v[j]), 4)
                         for name, v in sorted(metrics.items())}})
-            start += k
         if self.mesh is not None:
             first = (0,) * len(self.plan.axes)
             take0 = lambda t: jax.tree_util.tree_map(

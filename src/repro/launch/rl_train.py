@@ -45,9 +45,8 @@ spellings are bitwise-identical):
 The launcher forces enough fake host devices for the plan's mesh before
 jax loads. Training runs as fused supersteps: ``--superstep K``
 iterations of rollout -> learner_step -> lag-ring rotate execute inside
-one jitted ``lax.scan`` with a single host round-trip per dispatch;
-``--unfused`` falls back to per-iteration dispatch (same numerics, for
-debugging and the benchmarks/fused_superstep.py comparison).
+one jitted ``lax.scan`` with a single host round-trip per dispatch
+(``--superstep 1`` dispatches every iteration, with the same numerics).
 ``--pipeline`` decouples each iteration into a rollout producer and a
 learner consumer joined by a device-resident trajectory queue
 (repro.core.pipeline) whose depth is the staleness the plan's sync
@@ -153,15 +152,13 @@ def build_parser():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--no-vtrace", action="store_true",
                     help="impala only: naive targets instead of V-trace")
-    ap.add_argument("--unfused", action="store_true",
-                    help="per-iteration dispatch instead of fused scan")
     ap.add_argument("--pipeline", action="store_true",
                     help="decoupled actor-learner pipeline: split each "
                          "iteration into a rollout producer and learner "
                          "consumer joined by a device-resident "
                          "trajectory queue; the queue depth is what the "
                          "plan's per-axis sync discipline admits (bsp 0 "
-                         "= lockstep/bitwise-fused, ssp its bound, asp "
+                         "= lockstep with no queue, ssp its bound, asp "
                          "its max delay, summed over axes)")
     return ap
 
@@ -232,13 +229,13 @@ def main(argv=None):
     env = envs.make(args.env)
     t0 = time.time()
     trainer = Trainer(env, cfg)
-    _, history = trainer.fit(fused=not args.unfused)
+    _, history = trainer.fit()
     print(json.dumps({
         "algo": args.algo, "env": args.env, "policy": args.policy,
         "plan": plan.describe(),
-        "n_devices": plan.n_devices, "fused": not args.unfused,
+        "n_devices": plan.n_devices,
         # actor-learner pipeline: queue depth the plan's sync admits
-        # (0 = lockstep) and the ring capacity actually allocated
+        # and the queue's slots (depth 0 = lockstep, no queue: null)
         "pipeline": args.pipeline,
         "pipeline_depth": trainer.pipeline_depth,
         "pipeline_capacity": trainer.pipeline_capacity,

@@ -5,8 +5,7 @@ pinned headlines: BENCH_zero.json (per-device opt_state bytes shrink
 ~1/shard_size under the ZeRO-2 shard axis; params+opt <= 0.67x under
 the ZeRO-3 axis on the transformer trunk; peak live bytes strictly
 below replicated under the layer-wise gather), BENCH_hotpath.json
-(attention seam rows), BENCH_pipeline.json (every pipelined depth
-beats decoupled-serial), BENCH_serve.json (sane p50/p99 grid, zero
+(attention seam rows), BENCH_serve.json (sane p50/p99 grid, zero
 recompiles after warmup across hot-swaps), and BENCH_replay.json
 (per-device replay bytes <= 0.67x under the 2-shard replay axis)."""
 import glob
@@ -28,11 +27,10 @@ BENCH_FILES = sorted(glob.glob(os.path.join(REPO_ROOT, "BENCH_*.json")))
 def test_bench_files_exist():
     names = {os.path.basename(p) for p in BENCH_FILES}
     # the committed trajectory: hot path (PR 3), topologies/sync (PR 4),
-    # learner sharding (PR 5), actor-learner pipeline (PR 6),
-    # policy serving (PR 7), sharded replay (PR 9)
+    # learner sharding (PR 5), policy serving (PR 7), sharded replay
+    # (PR 9)
     assert {"BENCH_hotpath.json", "BENCH_topologies.json",
-            "BENCH_sync.json", "BENCH_zero.json",
-            "BENCH_pipeline.json", "BENCH_serve.json",
+            "BENCH_sync.json", "BENCH_zero.json", "BENCH_serve.json",
             "BENCH_replay.json"} <= names
 
 
@@ -204,33 +202,6 @@ def test_hotpath_bench_pins_attention_rows():
         assert "S=" in rows[name]["derived"], name
     assert "full_softmax" in rows["attention/naive_jnp"]["derived"]
     assert "interpret=" in rows["attention/flash_kernel"]["derived"]
-
-
-def test_pipeline_bench_pins_overlap_claim():
-    """Acceptance: BENCH_pipeline.json records the pipelined superstep
-    running strictly under the decoupled-serial rollout+learn sum
-    (overlap_fraction > 0) for EVERY depth >= 1 cell — the reason the
-    trajectory queue exists. Holds for the committed full run and for
-    the --quick regeneration CI does before this test."""
-    with open(os.path.join(REPO_ROOT, "BENCH_pipeline.json")) as f:
-        doc = validate_bench_json(json.load(f))
-    rows = {r["name"]: r for r in doc["rows"]}
-
-    def kv(name):
-        return dict(item.split("=", 1)
-                    for item in rows[name]["derived"].split(";"))
-
-    deep = [n for n in rows
-            if n.startswith("pipeline/") and n[-2:] in ("d1", "d2")]
-    assert len(deep) >= 4, sorted(rows)  # {ppo,dqn} x depths {1,2}
-    for name in deep:
-        d = kv(name)
-        assert float(d["pipe_us"]) < float(d["serial_sum_us"]), (name, d)
-        assert float(d["overlap_fraction"]) > 0, (name, d)
-        assert int(d["capacity"]) == int(d["depth"]), (name, d)
-    claim = kv("pipeline/overlap_claim")
-    assert claim["all_below_serial"] == "True", claim
-    assert float(claim["worst_overlap_fraction"]) > 0, claim
 
 
 def test_serve_bench_pins_latency_grid_and_flat_compiles():

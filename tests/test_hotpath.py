@@ -143,7 +143,22 @@ def test_donated_superstep_aliases_buffers():
 # ------------------------------------------------ fused sampling training
 def test_dqn_trains_with_fused_sampling():
     """DQN with the Gumbel-top-k sampler (kernel on TPU, ref oracle
-    here) trains end-to-end through the unchanged Trainer."""
+    here) trains end-to-end through the unchanged Trainer: its TD loss
+    at the last iteration clears an absolute bar.
+
+    The bar is on the loss, not the return. Iteration 0's return
+    averages only the episodes that finish inside the first unroll
+    (lucky near-goal starts, ~0.98), so it is no baseline. And 30
+    iterations are too few for the return to show learning: with
+    epsilon annealed over 20 steps the greedy policy has not yet
+    propagated the goal reward, and the return at iterations 20-29
+    sits near the -0.16 timeout with either sampler (the same run
+    reaches ~0.98 from iteration 60; test_trainer.py's
+    test_dqn_improves_on_gridworld pins convergence). The loss does
+    show it: the same run with lr=0 (a learner that fits nothing) ends
+    at a TD loss of ~0.06 on the CPU; the trained learner must end
+    below half of that (it reads ~0.007; its single-iteration losses
+    over iterations 20-29 stay under 0.03)."""
     from repro.envs import GridWorld
     env = GridWorld(n=4, max_steps=16)
     cfg = TrainerConfig(algo="dqn", iters=30, superstep=10, n_envs=16,
@@ -154,9 +169,8 @@ def test_dqn_trains_with_fused_sampling():
                                      "replay_capacity": 4096})
     _, hist = Trainer(env, cfg).fit()
     assert all(np.isfinite(r["loss"]) for r in hist)
-    final = hist[-1]["episode_return"]
-    assert np.isfinite(final) and final >= 0.8 * hist[0][
-        "episode_return"], hist
+    assert np.isfinite(hist[-1]["episode_return"]), hist
+    assert hist[-1]["loss"] < 0.03, hist
 
 
 # ------------------------------------------------------- bench JSON schema

@@ -1,5 +1,5 @@
 """Unified Agent/Trainer API under the Distribution Plan API: registry
-round-trip, fused-vs-unfused equivalence, the (collective x sync) smoke
+round-trip, superstep-size invariance, the (collective x sync) smoke
 matrix as 1-D plans on a fake 4-device mesh, the hierarchical 2-D plan
 matrix on 8 fake devices (incl. flat-vs-nested bitwise parity), the
 ZeRO shard-axis bitwise-parity matrix (all four algorithms), elastic
@@ -119,29 +119,6 @@ def test_episode_accounting_exact_and_carried():
 # tests/test_distribution.py)
 
 
-# ------------------------------------------- fused superstep equivalence
-def test_fused_superstep_equals_unfused():
-    """Acceptance: K fused iterations in one scan produce the same
-    params and metrics as per-iteration dispatch for a fixed seed."""
-    env = CartPole()
-
-    def run(fused):
-        cfg = TrainerConfig(algo="impala", iters=8, superstep=4,
-                            n_envs=8, unroll=8, log_every=4, seed=1,
-                            algo_kwargs={"hidden": (16,)})
-        return Trainer(env, cfg).fit(fused=fused)
-
-    s_fused, h_fused = run(True)
-    s_unfused, h_unfused = run(False)
-    for a, b in zip(jax.tree_util.tree_leaves(s_fused.params),
-                    jax.tree_util.tree_leaves(s_unfused.params)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
-    assert [r["iter"] for r in h_fused] == [r["iter"] for r in h_unfused]
-    for rf, ru in zip(h_fused, h_unfused):
-        assert rf["loss"] == pytest.approx(ru["loss"], rel=1e-3)
-
-
-# -------------------------------------------------- elastic actor shards
 def _hist_equal(h1, h2):
     """Bitwise history comparison; NaN (pre-first-episode) == NaN."""
     if len(h1) != len(h2):
@@ -156,21 +133,41 @@ def _hist_equal(h1, h2):
     return True
 
 
-def test_plan_elastic_actors_vary_shards_deterministically():
-    """plan.actors cycles the env-shard count per superstep window; the
-    per-shape numerics are pinned: two identical runs agree bitwise,
-    the shard trace is exactly the schedule, and the unfused fit
-    reshards at the same iteration boundaries (same numerics, one
-    schedule entry per cfg.superstep iterations)."""
+# ------------------------------------------- fused superstep equivalence
+def test_fused_superstep_equals_unfused():
+    """Acceptance: K iterations fused in one scan produce bitwise the
+    params and metrics of one dispatch per iteration (superstep=1) for
+    a fixed seed."""
     env = CartPole()
 
-    def run(fused=True):
+    def run(superstep):
+        cfg = TrainerConfig(algo="impala", iters=8, superstep=superstep,
+                            n_envs=8, unroll=8, log_every=4, seed=1,
+                            algo_kwargs={"hidden": (16,)})
+        return Trainer(env, cfg).fit()
+
+    s_fused, h_fused = run(4)
+    s_unfused, h_unfused = run(1)
+    for a, b in zip(jax.tree_util.tree_leaves(s_fused.params),
+                    jax.tree_util.tree_leaves(s_unfused.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert _hist_equal(h_fused, h_unfused)
+
+
+# -------------------------------------------------- elastic actor shards
+def test_plan_elastic_actors_vary_shards_deterministically():
+    """plan.actors cycles the env-shard count per superstep dispatch;
+    the per-shape numerics are pinned: two identical runs agree
+    bitwise, and the shard trace is exactly the schedule."""
+    env = CartPole()
+
+    def run():
         cfg = TrainerConfig(algo="impala", iters=9, superstep=3,
                             n_envs=8, unroll=6, log_every=1, seed=2,
                             plan=DistPlan.flat(1, actors=(8, 4, 8)),
                             algo_kwargs={"hidden": (8,)})
         tr = Trainer(env, cfg)
-        state, hist = tr.fit(fused=fused)
+        state, hist = tr.fit()
         return state, hist, tr.actor_shards
 
     s1, h1, shards1 = run()
@@ -179,12 +176,6 @@ def test_plan_elastic_actors_vary_shards_deterministically():
     assert _hist_equal(h1, h2)
     for a, b in zip(jax.tree_util.tree_leaves(s1.params),
                     jax.tree_util.tree_leaves(s2.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    s3, h3, shards3 = run(fused=False)
-    assert shards3 == [8] * 3 + [4] * 3 + [8] * 3  # per-dispatch trace
-    assert _hist_equal(h3, h1)
-    for a, b in zip(jax.tree_util.tree_leaves(s3.params),
-                    jax.tree_util.tree_leaves(s1.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
